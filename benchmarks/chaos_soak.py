@@ -43,6 +43,7 @@ import jax
 import numpy as np
 from _common import git_commit
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve.chaos import ChaosConfig, ChaosHarness
 
 N_SENSORS = int(os.environ.get("N_SENSORS", "6"))
@@ -55,6 +56,7 @@ REPO_ROOT = Path(__file__).resolve().parent.parent
 
 
 def main() -> None:
+    enable_compile_cache()
     cfg = ChaosConfig(
         n_sensors=N_SENSORS, n_faulty=N_FAULTY, n_rounds=N_ROUNDS, seed=SEED
     )

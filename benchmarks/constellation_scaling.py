@@ -58,6 +58,7 @@ from _common import git_commit
 
 from repro.core.pipeline import PipelineConfig
 from repro.core.pipeline.fleet import tier_capacity
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve.batcher import AdmissionConfig
 from repro.serve.constellation import ConstellationService
 
@@ -170,6 +171,7 @@ def _run_chaos() -> dict:
 
 
 def main() -> None:
+    enable_compile_cache()
     host_cores = os.cpu_count() or 1
     n_devices = len(jax.devices())
     gate_max_sensors = int(

@@ -70,6 +70,7 @@ from _common import git_commit
 from repro.core.pipeline import FleetPipeline, PipelineConfig, StreamingPipeline
 from repro.data.evas import iter_chunks
 from repro.data.synthetic import SCENARIO_FAMILIES, make_fleet_recordings
+from repro.launch.compile_cache import enable_compile_cache
 
 N_SENSORS = int(os.environ.get("N_SENSORS", "8"))
 DURATION_S = float(os.environ.get("DURATION_S", "3.0"))
@@ -151,6 +152,7 @@ def _replay_sequential(rounds, config):
 
 
 def main() -> None:
+    enable_compile_cache()
     config = PipelineConfig()  # paper defaults: 16px cells, 20 ms / 250 ev
     recs = _recordings()
     rounds = _rounds(recs)

@@ -142,19 +142,19 @@ def window_report(n_windows: int = 8, capacity: int = 256) -> dict:
     ``n_windows``-window batch step at the given capacity.
     """
     from repro.core.pipeline.config import PipelineConfig
-    from repro.launch.roofline import extract_terms
+    from repro.launch.hlo_analysis import analyze
 
     report: dict = {"n_windows": n_windows, "capacity": capacity, "rows": {}}
     for name, config in (
         ("float_staged", PipelineConfig()),
         ("fixed_staged", PipelineConfig(numerics="fixed")),
     ):
-        terms = extract_terms(
-            _compile_window_step(config, n_windows, capacity), n_devices=1
+        stats = analyze(
+            _compile_window_step(config, n_windows, capacity).as_text()
         )
         report["rows"][name] = {
-            "flops": terms.flops,
-            "bytes": terms.hbm_bytes,
+            "flops": stats["flops"],
+            "bytes": stats["bytes"],
             "launches": float(n_windows),  # one logical step per window
         }
     report["rows"]["megakernel_model"] = _megakernel_cost_model(

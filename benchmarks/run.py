@@ -2,9 +2,9 @@
 
 Two kinds of entries share this single entrypoint:
 
-* **table/figure modules** (``table1`` .. ``roofline``) — imported and
-  run in-process, printing ``name,us_per_call,derived`` CSV rows (the
-  paper-reproduction numbers).
+* **table/figure modules** (``table1`` .. ``roofline``) — each run in a
+  child process, printing ``name,us_per_call,derived`` CSV rows (the
+  paper-reproduction numbers). A module that raises fails the run.
 * **gated benches** (``scan`` / ``stream`` / ``fleet``) — run as
   subprocesses writing ``BENCH_<name>.json`` at the repo root. Every
   payload carries a uniform ``bench`` block — ``{name, p50_ms, p99_ms,
@@ -16,6 +16,9 @@ Two kinds of entries share this single entrypoint:
   ``BENCH_GATE_SPEEDUP`` / ``BENCH_GATE_EVENT`` pass through and mean
   the same thing here as when a bench is run directly); the aggregator
   exits nonzero iff any subprocess did.
+
+The aggregator itself never imports JAX: a device belongs to one process
+at a time, so every entry runs in its own child, one after another.
 
 Select subsets by key::
 
@@ -30,8 +33,6 @@ import subprocess
 import sys
 import time
 from pathlib import Path
-
-from benchmarks._common import emit
 
 REPO_ROOT = Path(__file__).resolve().parent.parent
 
@@ -58,15 +59,25 @@ BENCHES = {
 }
 
 
-def _run_module(key: str) -> None:
+# Child-process body for one table/figure module: import it, run its
+# ``bench()``, print the CSV rows. An exception exits the child nonzero.
+_MODULE_CHILD = (
+    "import importlib, sys\n"
+    "from benchmarks._common import emit\n"
+    "emit(importlib.import_module(sys.argv[1]).bench())\n"
+)
+
+
+def _run_module(key: str) -> bool:
+    """Run one table/figure module in a child; True iff it succeeded."""
     t0 = time.time()
-    mod = __import__(MODULES[key], fromlist=["bench"])
-    try:
-        rows = mod.bench()
-    except Exception as e:  # noqa: BLE001
-        rows = [(f"{key}/ERROR", 0.0, f"{type(e).__name__}_{e}")]
-    emit(rows)
-    print(f"# {key} done in {time.time() - t0:.1f}s", file=sys.stderr)
+    proc = subprocess.run(
+        [sys.executable, "-c", _MODULE_CHILD, MODULES[key]], cwd=REPO_ROOT
+    )
+    ok = proc.returncode == 0
+    status = "done" if ok else f"FAILED (exit {proc.returncode})"
+    print(f"# {key} {status} in {time.time() - t0:.1f}s", file=sys.stderr)
+    return ok
 
 
 def _run_bench(key: str) -> tuple[dict | None, bool]:
@@ -95,16 +106,20 @@ def main() -> None:
                  f"choose from {[*MODULES, *BENCHES]}")
 
     if any(k in MODULES for k in selected):
-        print("name,us_per_call,derived")
+        print("name,us_per_call,derived", flush=True)
     summaries: list[tuple[str, dict | None, bool]] = []
+    failed_modules = []
     for key in selected:
         if key in MODULES:
-            _run_module(key)
+            if not _run_module(key):
+                failed_modules.append(key)
         else:
             block, ok = _run_bench(key)
             summaries.append((key, block, ok))
 
     if not summaries:
+        if failed_modules:
+            sys.exit(f"table/figure modules failed: {failed_modules}")
         return
     print(f"\n{'bench':<18} {'p50 ms':>9} {'p99 ms':>9} {'bytes/round':>12}  gates")
     failed = False
@@ -129,7 +144,9 @@ def main() -> None:
             f"{block['name']:<18} {block['p50_ms']:>9} {block['p99_ms']:>9} "
             f"{bpr_col:>12}  {gates}{'' if ok else '  << exit 1'}"
         )
-    if failed:
+    if failed_modules:
+        print(f"table/figure modules failed: {failed_modules}")
+    if failed or failed_modules:
         sys.exit(1)
 
 

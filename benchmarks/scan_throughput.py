@@ -59,6 +59,7 @@ from repro.core.pipeline import (
     tracker_step,
 )
 from repro.data.synthetic import Recording, make_recording
+from repro.launch.compile_cache import enable_compile_cache
 
 N_WINDOWS = int(os.environ.get("N_WINDOWS", "64"))
 # The megakernel rows use a smaller window count: interpret-mode Pallas
@@ -133,6 +134,7 @@ def _stage_breakdown(
 
 
 def main() -> None:
+    enable_compile_cache()
     config = PipelineConfig()  # metrics_impl="event" default
     config_frame = dataclasses.replace(config, metrics_impl="frame")
     rec = _recording_with_windows(N_WINDOWS)

@@ -54,6 +54,7 @@ from repro.core.pipeline import FleetPipeline, PipelineConfig
 from repro.core.pipeline import fleet as fleet_mod
 from repro.data.evas import iter_chunks
 from repro.data.synthetic import SCENARIO_FAMILIES, make_fleet_recordings
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import AdmissionConfig, DetectionService
 
 N_SESSIONS = int(os.environ.get("N_SESSIONS", "8"))
@@ -200,6 +201,7 @@ def _host_view_bench(slots: int = 32, hot: int = 2, iters: int = 30):
 
 
 def main() -> None:
+    enable_compile_cache()
     # Enough distinct recordings for the whole churn schedule, per pass.
     n_recs = CHURN_START + N_SESSIONS + N_ROUNDS // CHURN_EVERY + 2
     recordings = [_recording(i) for i in range(n_recs)]

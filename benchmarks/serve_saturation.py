@@ -59,6 +59,7 @@ import numpy as np
 from _common import git_commit
 
 from repro.core.pipeline import PipelineConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import AdmissionConfig, DetectionService
 
 N_SESSIONS = int(os.environ.get("N_SESSIONS", "8"))
@@ -184,6 +185,7 @@ def _knee(rows):
 
 
 def main() -> None:
+    enable_compile_cache()
     host_cores = os.cpu_count() or 1
     ratio_target = RATIO_TARGET_MULTICORE if host_cores >= 2 else RATIO_FLOOR_1CORE
     ratio_target = float(os.environ.get("BENCH_GATE_RATIO", ratio_target))

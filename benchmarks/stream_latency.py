@@ -32,6 +32,7 @@ from _common import git_commit
 from repro.core.events import stride_bounds
 from repro.core.pipeline import PipelineConfig, StreamingPipeline
 from repro.data.synthetic import make_recording
+from repro.launch.compile_cache import enable_compile_cache
 
 DURATION_S = float(os.environ.get("DURATION_S", "3.0"))
 CHUNK_US = int(os.environ.get("CHUNK_US", "20000"))
@@ -66,6 +67,7 @@ def _replay(rec, chunks, config) -> tuple[list[float], int]:
 
 
 def main() -> None:
+    enable_compile_cache()
     config = PipelineConfig()  # paper defaults: 16px cells, 20 ms / 250 ev
     rec = make_recording(seed=0, duration_s=DURATION_S, n_rsos=2)
     chunks = _chunks(rec)

@@ -11,7 +11,7 @@ import numpy as np
 from repro.core.pipeline import PipelineConfig, merge_candidates, collect_candidates, score_threshold
 from repro.core.pipeline import run_recording
 from repro.data.synthetic import make_recording
-from repro.launch.mesh import HBM_BW
+from repro.launch.mesh import TARGET_DEVICE_KIND, chip_peaks
 
 
 def bench() -> list[tuple[str, float, str]]:
@@ -47,7 +47,7 @@ def bench() -> list[tuple[str, float, str]]:
 
     # Quantize-kernel roofline on the TPU target: 4B in + 4B out per event
     # at HBM bandwidth (the stream is too light to be compute-bound).
-    ev_per_s = HBM_BW / 8.0
+    ev_per_s = chip_peaks(TARGET_DEVICE_KIND).hbm_bw / 8.0
     rows.append(
         ("table4/quantize_kernel_roofline", 0.0,
          f"{ev_per_s / 1e9:.0f}GEv_s_vs_paper_0.2GEv_s")

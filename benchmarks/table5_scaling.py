@@ -6,7 +6,8 @@ One mesh-axis shard per camera node via the pipeline's ``mesh=``
 support; each node carries ``PER_NODE`` sensors (weak scaling, the
 paper's deployment shape: more ground stations, same per-station load).
 Runs in subprocesses so each node count gets its own
-``--xla_force_host_platform_device_count``.
+``--xla_force_host_platform_device_count``. The children are CPU-only by
+design (``JAX_PLATFORMS=cpu``), so they never contend for an accelerator.
 """
 from __future__ import annotations
 
@@ -64,6 +65,7 @@ def bench(
     for nodes in node_counts:
         env = dict(os.environ)
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={nodes}"
+        env["JAX_PLATFORMS"] = "cpu"
         env["PYTHONPATH"] = str(SRC)
         out = subprocess.run(
             [sys.executable, "-c", _SNIPPET.format(nodes=nodes)],

@@ -19,6 +19,7 @@ import dataclasses
 from repro.core.pipeline import PipelineConfig
 from repro.data.evas import iter_chunks
 from repro.data.synthetic import SCENARIO_FAMILIES, make_fleet_recordings
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import ConstellationService, FaultConfig
 from repro.serve.chaos import _FlakyFleet
 
@@ -35,6 +36,7 @@ def _recording(idx: int):
 
 
 def main() -> None:
+    enable_compile_cache()
     config = PipelineConfig()  # paper defaults: 16px cells, 20 ms / 250 ev
     cs = ConstellationService(
         config,

@@ -21,12 +21,14 @@ from repro.core.pipeline import FleetPipeline, PipelineConfig
 from repro.core.tracking import confirmed
 from repro.data.evas import iter_chunks
 from repro.data.synthetic import SCENARIO_FAMILIES, make_fleet_recordings
+from repro.launch.compile_cache import enable_compile_cache
 
 CHUNK_US = 20_000  # feed 20 ms per sensor per round
 FAMILIES = ("crossing", "geo_slow", "tumbling", "ballistic")
 
 
 def main() -> None:
+    enable_compile_cache()
     print(f"Generating a {len(FAMILIES)}-sensor scenario-diverse sky (2 s)...")
     recs = [
         dataclasses.replace(

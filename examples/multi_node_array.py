@@ -30,10 +30,12 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 from repro.core.events import EventBatch  # noqa: E402
 from repro.core.grid_clustering import GridConfig, grid_cluster  # noqa: E402
 from repro.data.synthetic import make_recording  # noqa: E402
-from repro.launch.mesh import make_mesh, shard_map  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--nodes", type=int, default=N_NODES)
     ap.add_argument("--windows", type=int, default=64)
@@ -77,7 +79,7 @@ def main() -> None:
             out = jax.vmap(lambda eb: grid_cluster(eb, grid).count)(b)
             return out[None]
 
-        return shard_map(
+        return jax.shard_map(
             node_fn, mesh=mesh,
             in_specs=(jax.tree.map(lambda _: P("node"), batch),),
             out_specs=P("node"),

@@ -20,8 +20,10 @@ import numpy as np
 from repro.core.pipeline import PipelineConfig, run_recording_scan, evaluate_detection
 from repro.core.tracking import confirmed
 from repro.data.synthetic import make_recording
+from repro.launch.compile_cache import enable_compile_cache
 
 def main() -> None:
+    enable_compile_cache()
     print("Generating a 2 s synthetic EVAS-like recording (2 RSOs)...")
     rec = make_recording(seed=7, duration_s=2.0, n_rsos=2, lens="standard")
     print(f"  {len(rec):,} events "

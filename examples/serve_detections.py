@@ -21,6 +21,7 @@ from repro.core.pipeline import PipelineConfig
 from repro.core.tracking import confirmed
 from repro.data.evas import iter_chunks
 from repro.data.synthetic import SCENARIO_FAMILIES, make_fleet_recordings
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import DetectionService
 
 CHUNK_US = 20_000  # live cadence: one 20 ms chunk per sensor per round
@@ -36,6 +37,7 @@ def _recording(idx: int):
 
 
 def main() -> None:
+    enable_compile_cache()
     config = PipelineConfig()  # paper defaults: 16px cells, 20 ms / 250 ev
     svc = DetectionService(config, tiers=(4, 8, 16))
     print(f"DetectionService up: tier capacity {svc.capacity} slots")

@@ -21,11 +21,13 @@ from repro.core.events import stride_bounds
 from repro.core.pipeline import PipelineConfig, StreamingPipeline
 from repro.core.tracking import confirmed
 from repro.data.synthetic import make_recording
+from repro.launch.compile_cache import enable_compile_cache
 
 CHUNK_US = 20_000  # feed 20 ms of events at a time
 
 
 def main() -> None:
+    enable_compile_cache()
     print("Generating a 2 s synthetic EVAS-like recording (2 RSOs)...")
     rec = make_recording(seed=7, duration_s=2.0, n_rsos=2, lens="standard")
     print(f"  {len(rec):,} events")

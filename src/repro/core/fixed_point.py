@@ -215,12 +215,13 @@ def clusters_fixed_from_stats(
 # ---------------------------------------------------------------------------
 
 def sobel_int(patch: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """3x3 Sobel on an integer count patch — pure int32 shift-and-add."""
+    """3x3 Sobel on an integer count patch — pure int32 shift-and-add over
+    static slices, so the megakernel runs this same code inside Mosaic."""
     h, w = patch.shape
     padded = jnp.pad(patch, 1)
 
     def shift(dy: int, dx: int) -> jax.Array:
-        return jax.lax.dynamic_slice(padded, (dy, dx), (h, w))
+        return padded[dy:dy + h, dx:dx + w]
 
     left, right = shift(1, 0), shift(1, 2)
     up, down = shift(0, 1), shift(2, 1)
@@ -251,8 +252,6 @@ def fixed_metric_epilogue(
     same expressions as ``metrics._exact_cluster_metrics`` over the same
     integers and stay bit-identical to the float golden model too.
     """
-    histf = hist_i.astype(jnp.float32)
-    p = histf / jnp.maximum(histf.sum(), 1.0)
     norm = norm_i.astype(jnp.float32)
 
     mean = s1.astype(jnp.float32) / n
@@ -269,8 +268,8 @@ def fixed_metric_epilogue(
     diff_entropy = 0.5 * jnp.log2(2.0 * jnp.pi * jnp.e * var_g)
 
     m = {
-        "shannon_entropy": M._shannon_from_hist(p),
-        "renyi_entropy": M._renyi_from_hist(p),
+        "shannon_entropy": M._shannon_from_counts(hist_i),
+        "renyi_entropy": M._renyi_from_counts(hist_i),
         "differential_entropy": diff_entropy,
         "local_contrast": contrast,
         "edge_density": edges.astype(jnp.float32) / n,

@@ -208,10 +208,13 @@ def merge_adjacent(clusters: Clusters, config: GridConfig) -> Clusters:
     is_root = parent == jnp.arange(k)
     onehot = jax.nn.one_hot(parent, k, dtype=jnp.float32)  # (child, root)
     w = counts * clusters.valid
-    merged_count = (w @ onehot).astype(jnp.int32)
-    merged_x = (w * clusters.centroid_x) @ onehot
-    merged_y = (w * clusters.centroid_y) @ onehot
-    merged_t = (w * clusters.centroid_t) @ onehot
+    # Full f32 passes: a default single bf16 pass on the TPU's MXU would
+    # round the weighted coordinate and time sums.
+    dot = lambda a: jnp.matmul(a, onehot, precision=jax.lax.Precision.HIGHEST)
+    merged_count = dot(w).astype(jnp.int32)
+    merged_x = dot(w * clusters.centroid_x)
+    merged_y = dot(w * clusters.centroid_y)
+    merged_t = dot(w * clusters.centroid_t)
     denom = jnp.maximum(merged_count.astype(jnp.float32), 1.0)
     valid = is_root & clusters.valid & (merged_count >= 1)
     return Clusters(

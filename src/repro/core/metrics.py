@@ -104,28 +104,53 @@ def _histogram_counts(patch: jax.Array, bins: int = HIST_BINS) -> jax.Array:
     return onehot.sum(axis=1, dtype=jnp.int32).astype(jnp.float32)
 
 
-def _histogram(patch: jax.Array, bins: int = HIST_BINS) -> jax.Array:
-    """Normalized intensity histogram (differentiable-ish, fixed shape)."""
-    counts = _histogram_counts(patch, bins)
-    return counts / jnp.maximum(counts.sum(), 1.0)
+def _ordered_sum(x: jax.Array) -> jax.Array:
+    """Sum over the last axis in one fixed pairwise order.
+
+    ``jnp.sum`` leaves a float reduction's order to the compiler, and
+    XLA:TPU picks it per layout: the same values summed inside a batch of
+    256 patches and inside a batch of 16 x 32 can differ in the last bit,
+    which breaks the served-vs-scan bit-identity on the chip. Adding the
+    halves of explicit slices pins the order (XLA does not reassociate
+    float adds); odd lengths take a zero, which adds exactly.
+    """
+    while x.shape[-1] > 1:
+        if x.shape[-1] % 2:
+            x = jnp.concatenate([x, jnp.zeros_like(x[..., :1])], axis=-1)
+        half = x.shape[-1] // 2
+        x = x[..., :half] + x[..., half:]
+    return x[..., 0]
 
 
-def _shannon_from_hist(p: jax.Array) -> jax.Array:
-    return -jnp.sum(jnp.where(p > 0, p * jnp.log2(jnp.maximum(p, 1e-12)), 0.0))
+def _shannon_from_counts(counts: jax.Array) -> jax.Array:
+    """H = -sum p_i log2 p_i from integer histogram counts (any dtype)."""
+    c = counts.astype(jnp.float32)
+    p = c / jnp.maximum(jnp.sum(c), 1.0)  # exact integer sum
+    return -_ordered_sum(
+        jnp.where(p > 0, p * jnp.log2(jnp.maximum(p, 1e-12)), 0.0)
+    )
 
 
-def _renyi_from_hist(p: jax.Array) -> jax.Array:
-    return -jnp.log2(jnp.maximum(jnp.sum(p * p), 1e-12))
+def _renyi_from_counts(counts: jax.Array) -> jax.Array:
+    """H2 = -log2 sum p_i^2 from integer histogram counts (any dtype).
+
+    sum p_i^2 = sum c_i^2 / (sum c_i)^2 with both sums in int32, so no
+    float reduction (and no reduction order) enters the result.
+    """
+    c = counts.astype(jnp.int32)
+    total = jnp.maximum(jnp.sum(c), 1).astype(jnp.float32)
+    sq = jnp.sum(c * c).astype(jnp.float32)
+    return -jnp.log2(jnp.maximum(sq / (total * total), 1e-12))
 
 
 def shannon_entropy(patch: jax.Array, bins: int = HIST_BINS) -> jax.Array:
     """H = -sum p_i log2 p_i over the intensity histogram."""
-    return _shannon_from_hist(_histogram(patch, bins))
+    return _shannon_from_counts(_histogram_counts(patch, bins))
 
 
 def renyi_entropy(patch: jax.Array, bins: int = HIST_BINS) -> jax.Array:
     """H2 = -log2 sum p_i^2 (collision entropy)."""
-    return _renyi_from_hist(_histogram(patch, bins))
+    return _renyi_from_counts(_histogram_counts(patch, bins))
 
 
 def _sobel(patch: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -134,12 +159,14 @@ def _sobel(patch: jax.Array) -> tuple[jax.Array, jax.Array]:
     Zero-padded shifts match conv_general_dilated's SAME behaviour but
     lower to six adds per axis — far cheaper than a general convolution on
     CPU/VPU for a fixed 3x3 stencil, and fully fusable inside scan bodies.
+    The shifts are static slices, which Mosaic lowers inside a Pallas TPU
+    kernel too (a ``dynamic_slice`` it does not).
     """
     h, w = patch.shape
     padded = jnp.pad(patch, 1)
 
     def shift(dy: int, dx: int) -> jax.Array:
-        return jax.lax.dynamic_slice(padded, (dy, dx), (h, w))
+        return padded[dy:dy + h, dx:dx + w]
 
     left = shift(1, 0)
     right = shift(1, 2)
@@ -187,10 +214,12 @@ def edge_density(patch: jax.Array, threshold: float = 0.25) -> jax.Array:
     return _edge_density_from_g(gradient_magnitude(patch), threshold)
 
 
+@jax.jit
 def cluster_metrics(frame: jax.Array, clusters: Clusters) -> dict[str, jax.Array]:
     """Vectorized metric computation for every cluster slot. Invalid slots
     get zeros. Returns a dict of (K,) arrays keyed by metric name.
 
+    Jitted: op-by-op dispatch of this many small ops costs seconds.
     Legacy reference operating on a pre-normalized frame; the pipeline
     routes through :func:`cluster_metrics_frame` /
     :func:`cluster_metrics_events` instead, which share the
@@ -200,11 +229,11 @@ def cluster_metrics(frame: jax.Array, clusters: Clusters) -> dict[str, jax.Array
 
     def per_cluster(cx, cy, count, valid):
         patch = extract_window(frame, cx, cy)
-        p = _histogram(patch)
+        counts = _histogram_counts(patch)
         g = gradient_magnitude(patch)
         m = {
-            "shannon_entropy": _shannon_from_hist(p),
-            "renyi_entropy": _renyi_from_hist(p),
+            "shannon_entropy": _shannon_from_counts(counts),
+            "renyi_entropy": _renyi_from_counts(counts),
             "differential_entropy": _diff_entropy_from_g(g),
             "local_contrast": local_contrast(patch),
             "edge_density": _edge_density_from_g(g),
@@ -220,8 +249,9 @@ def cluster_metrics(frame: jax.Array, clusters: Clusters) -> dict[str, jax.Array
 # ---------------------------------------------------------------------------
 # Exactly-replayable metric core, shared by the frame-based oracle and the
 # frame-free event-space path (DESIGN.md Sec. 4). Every quantity entering a
-# float reduction is either an exact small integer (order-independent sum)
-# or computed densely from identical integer inputs in both paths.
+# reduction is either an exact small integer (order-independent sum) or
+# summed in one fixed order (:func:`_ordered_sum`), so the result does not
+# depend on how the compiler batches or lays out the patches.
 # ---------------------------------------------------------------------------
 
 def _exact_cluster_metrics(
@@ -241,10 +271,11 @@ def _exact_cluster_metrics(
     with event-side moments pass them via ``moments`` and skip two dense
     passes; the sums are exact integers either way, so the result is
     bit-identical. The gradient-magnitude statistics run densely on the
-    count patch, which both paths materialize bit-identically.
+    count patch, which both paths materialize bit-identically: the
+    squared magnitudes sum as exact integers, the magnitudes in one fixed
+    order.
     """
     n = cnt_patch.size
-    p = hist_counts / jnp.maximum(hist_counts.sum(), 1.0)
 
     # Local contrast: std of normalized intensities via integer moments.
     if moments is None:
@@ -258,20 +289,13 @@ def _exact_cluster_metrics(
 
     # Gradient field of the integer counts (Sobel outputs stay integer).
     gx, gy = _sobel(cnt_patch)
-    e2 = (gx * gx + gy * gy) / (norm * norm) + 1e-12  # squared magnitude
+    g2 = gx * gx + gy * gy  # exact integers, summed in int32 below
+    e2 = g2 / (norm * norm) + 1e-12  # squared magnitude
     g = jnp.sqrt(e2)
-    # One variadic reduce for sum(g) / sum(e2) / max(e2): three separate
-    # jnp reductions each force the whole e2/g field to materialize and
-    # be re-read, which costs more than the Sobel itself on CPU; a
-    # single fused reduce streams the field once. (Float summation
-    # order is unspecified either way; every metrics path shares this
-    # function, so cross-driver bit-identity is structural.)
-    s_g, s_e2, mx_e2 = jax.lax.reduce(
-        (g, e2, e2),
-        (jnp.float32(0.0), jnp.float32(0.0), jnp.float32(-jnp.inf)),
-        lambda a, b: (a[0] + b[0], a[1] + b[1], jnp.maximum(a[2], b[2])),
-        (0, 1),
-    )
+    s_g = _ordered_sum(g.reshape(-1))
+    s_e2 = jnp.sum(g2.astype(jnp.int32)).astype(jnp.float32) / (
+        norm * norm
+    ) + n * 1e-12
     m1 = s_g / n
     var_g = jnp.maximum(s_e2 / n - m1 * m1, 1e-12)
     diff_entropy = 0.5 * jnp.log2(2.0 * jnp.pi * jnp.e * var_g)
@@ -279,14 +303,14 @@ def _exact_cluster_metrics(
     # Edge density: g / max(g.max(), 1e-3) > t, evaluated in squared
     # magnitude space (sqrt is monotone, so max commutes; the count of
     # edge pixels is an exact integer sum).
-    den = jnp.maximum(jnp.sqrt(mx_e2), 1e-3)
+    den = jnp.maximum(jnp.sqrt(jnp.max(e2)), 1e-3)
     thr = (EDGE_THRESHOLD * den) * (EDGE_THRESHOLD * den)
     edges = jnp.sum((e2 > thr).astype(jnp.float32))
     edge_density_v = edges / n
 
     m = {
-        "shannon_entropy": _shannon_from_hist(p),
-        "renyi_entropy": _renyi_from_hist(p),
+        "shannon_entropy": _shannon_from_counts(hist_counts),
+        "renyi_entropy": _renyi_from_counts(hist_counts),
         "differential_entropy": diff_entropy,
         "local_contrast": contrast,
         "edge_density": edge_density_v,
@@ -368,7 +392,9 @@ def event_histogram_counts(
     ).astype(jnp.float32)
 
     lead_inp = inp * leader.astype(jnp.float32)[None, :]
-    hist = lead_inp @ bins_onehot  # (K, bins) exact integer counts
+    hist = jnp.matmul(  # (K, bins) exact integer counts: full f32 passes
+        lead_inp, bins_onehot, precision=jax.lax.Precision.HIGHEST
+    )
     occ = jnp.sum(lead_inp, axis=-1)
     hist = hist.at[:, 0].add(window * window - occ)
     # Moments: sum of pixel counts == events in patch; sum of squared
@@ -414,6 +440,7 @@ def cluster_metrics_events(
     clusters: Clusters,
     width: int = 640,
     height: int = 480,
+    count_patches=cluster_count_patches,
 ) -> dict[str, jax.Array]:
     """Frame-free metrics: O(E + K * patch^2) per window, bit-identical to
     :func:`cluster_metrics_frame`.
@@ -421,14 +448,16 @@ def cluster_metrics_events(
     The normalizer comes from per-pixel coincidence counts, histogram
     counts from leader events, and each cluster's count patch is
     accumulated directly from events — ``reconstruct_frame`` and the
-    sensor-sized scatter never run.
+    sensor-sized scatter never run. ``count_patches`` builds the patches
+    (same signature as :func:`cluster_count_patches`); the Pallas
+    ``patch_metrics`` kernel route passes its own.
     """
     c, leader, w, norm = event_normalizer(batch, width, height)
     x0, y0 = window_origin(
         clusters.centroid_x, clusters.centroid_y, width, height
     )
     hist, moments = event_histogram_counts(batch, c, leader, w, norm, x0, y0)
-    patches = cluster_count_patches(batch, clusters, width, height)
+    patches = count_patches(batch, clusters, width, height)
     return jax.vmap(_exact_cluster_metrics)(
         patches, hist, jnp.broadcast_to(norm, x0.shape), clusters.count,
         clusters.valid, moments,

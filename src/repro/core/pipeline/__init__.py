@@ -75,6 +75,7 @@ from repro.core.pipeline.evaluate import (  # noqa: F401
     collect_candidates_fleet,
     collect_candidates_many,
     evaluate_detection,
+    match_candidates,
     merge_candidates,
     score_threshold,
     threshold_sweep,

@@ -13,8 +13,9 @@ This is the one place the pipeline's tuning knobs are documented
   - ``"frame"``: the paper's original data flow — sensor-sized
     accumulation image, global-max normalizer, patch slicing. O(sensor
     area) per window; kept as the bit-exactness oracle.
-  - ``"kernel"``: the fused Pallas ``patch_metrics`` kernel
-    (interpret-mode on CPU, compiled on TPU).
+  - ``"kernel"``: the event path with its count patches built by the
+    Pallas ``patch_metrics`` kernel (interpret-mode on CPU, compiled on
+    TPU).
 
 * ``scan_chunk`` — window-block size for the event-space driver's
   batched conditioning/clustering/stats phases (DESIGN.md Sec. 5). A

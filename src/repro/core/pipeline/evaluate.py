@@ -243,18 +243,39 @@ def collect_candidates(
     result = run_recording_scan(
         recording, _floor_config(config, candidate_floor), with_tracking=False
     )
-    windows = result.windows
-    cl = result.clusters
-    t_rel, tracks = _rebase_times(windows.t_start_us, recording.rso_tracks)
+    return match_candidates(
+        recording, result.clusters, result.windows.t_start_us,
+        result.windows.stops, max_samples, gate_px, min_truth_events,
+    )
+
+
+def match_candidates(
+    recording: Recording,
+    clusters,
+    t_start_us: np.ndarray,
+    stops: np.ndarray,
+    max_samples: int | None = None,
+    gate_px: float = 14.0,
+    min_truth_events: int = 3,
+) -> Candidates:
+    """Truth-match one recording's stacked pipeline outputs.
+
+    ``clusters`` has (W, K) leaves, ``t_start_us`` and ``stops`` are the
+    windows' origins and exclusive slice stops — from any driver: the
+    scan (:func:`collect_candidates`) or a served session's concatenated
+    feeds, which score identically when they are bit-identical.
+    """
+    cl = clusters
+    t_rel, tracks = _rebase_times(np.asarray(t_start_us), recording.rso_tracks)
     k = cl.count.shape[-1] if cl.count.ndim == 2 else 0
-    ms = windows.num_windows * k if max_samples is None else max_samples
+    ms = len(t_rel) * k if max_samples is None else max_samples
     is_rso, keep, best = _match_one(
         cl.count, cl.valid, cl.centroid_x, cl.centroid_y, cl.centroid_t,
         jnp.asarray(t_rel), jnp.asarray(tracks),
         jnp.float32(gate_px), ms,
     )
     return _assemble_candidates(
-        recording, windows.stops, np.asarray(cl.count), np.asarray(is_rso),
+        recording, np.asarray(stops), np.asarray(cl.count), np.asarray(is_rso),
         np.asarray(keep), np.asarray(best), min_truth_events,
     )
 

@@ -247,6 +247,11 @@ def make_wire_fn(capacity: int, use_kernels: bool):
         unpack_impl = None
 
     def decode(words, dt16, pol, offsets, spill):
+        # Staged views may arrive in pinned host memory (see
+        # _pinned_host_sharding); the decode itself runs in device memory.
+        words, dt16, pol, offsets, spill = jax.device_put(
+            (words, dt16, pol, offsets, spill), jax.memory.Space.Device
+        )
         packed, valid = unpack_wire(
             words, dt16, pol, offsets, spill, capacity, unpack_impl
         )
@@ -256,40 +261,41 @@ def make_wire_fn(capacity: int, use_kernels: bool):
     return jax.jit(decode)
 
 
-@functools.lru_cache(maxsize=1)
-def _pinned_host_sharding():
-    """Pinned-host placement for wire staging, when the backend has one.
+def _pinned_host_sharding(carry: jax.Array):
+    """Pinned-host staging placement on the device(s) that hold ``carry``.
 
     On accelerator backends whose devices expose a ``pinned_host``
     memory space (TPU/GPU runtimes), host->device DMA from pinned pages
     avoids a driver-side bounce copy; the ragged dispatch routes its
-    wire views through this placement first. CPU backends (host memory
-    IS device memory) and runtimes without the memory space return
-    ``None`` and the views ship as plain numpy — behaviour, and bits,
-    are identical either way.
+    wire views through this placement first. The placement follows the
+    carry — its device, or its mesh with the wire replicated — so a
+    fleet living on one chip of several stages and decodes there.
+    CPU backends (host memory IS device memory) and runtimes without
+    the memory space return ``None`` and the views ship as plain numpy:
+    behaviour, and bits, are identical either way.
     """
     if jax.default_backend() == "cpu":
         return None
-    try:
-        dev = jax.devices()[0]
-        kinds = {m.kind for m in dev.addressable_memories()}
-        if "pinned_host" not in kinds:
-            return None
-        return jax.sharding.SingleDeviceSharding(dev, memory_kind="pinned_host")
-    except Exception:  # pragma: no cover - runtime-dependent introspection
+    sharding = carry.sharding
+    devices = sharding.device_set
+    if any(
+        "pinned_host" not in {m.kind for m in d.addressable_memories()}
+        for d in devices
+    ):
         return None
+    if len(devices) == 1:
+        return jax.sharding.SingleDeviceSharding(
+            next(iter(devices)), memory_kind="pinned_host"
+        )
+    return jax.sharding.NamedSharding(
+        sharding.mesh, jax.sharding.PartitionSpec(), memory_kind="pinned_host"
+    )
 
 
-def _stage_wire(views: tuple) -> tuple:
-    """Bounce the per-round wire views through pinned host memory when
-    the backend supports it (see :func:`_pinned_host_sharding`)."""
-    sharding = _pinned_host_sharding()
-    if sharding is None:
-        return views
-    try:
-        return tuple(jax.device_put(v, sharding) for v in views)
-    except Exception:  # pragma: no cover - degrade to plain numpy inputs
-        return views
+def _stage_wire(views: tuple, sharding) -> tuple:
+    """Place the per-round wire views with ``sharding`` (from
+    :func:`_pinned_host_sharding`); ``None`` ships them as numpy."""
+    return views if sharding is None else jax.device_put(views, sharding)
 
 
 @dataclasses.dataclass
@@ -743,6 +749,7 @@ class FleetPipeline:
             raise ValueError(
                 f"state has {state.n_sensors} sensors, pipeline expects {n_sensors}"
             )
+        self._wire_staging = _pinned_host_sharding(self.state.atlas)
 
     def init_state(self) -> FleetState:
         s = self.n_sensors
@@ -761,9 +768,7 @@ class FleetPipeline:
     def _mesh_ctx(self):
         if self.mesh is None:
             return contextlib.nullcontext()
-        from repro.launch.mesh import use_mesh  # one jax-compat shim, one home
-
-        return use_mesh(self.mesh)
+        return jax.set_mesh(self.mesh)
 
     def feed(self, chunks, final=False) -> FleetResult:
         """Ingest one chunk per sensor; process every closed window in one
@@ -1119,7 +1124,7 @@ class FleetPipeline:
                 staging.words[:n_pad], staging.dt[:n_pad],
                 staging.pol[: n_pad // 32], staging.offsets,
                 staging.spill[:, :m_pad],
-            ))
+            ), self._wire_staging)
             wire_b = ragged_wire_bytes(n_pad, s_count, w_max, m_pad)
         else:
             wire_b = dense_wire_bytes(s_count, w_max, cap)

@@ -140,17 +140,18 @@ class StreamingPipeline:
         # wire machinery (shared with the fleet engine) has to come in at
         # construction, not at module import.
         from repro.core.pipeline.fleet import (
-            WireStats, _stage_wire, make_wire_fn,
+            WireStats, _pinned_host_sharding, _stage_wire, make_wire_fn,
         )
 
         self.wire_stats = WireStats()
         if wire == "ragged":
             self._wire = make_wire_fn(config.batcher.capacity, config.use_kernels)
-            self._stage_wire = _stage_wire
         else:
             self._wire = None
         self._tag_limit = tag_limit(config)
         self.state = self.init_state() if state is None else state
+        self._wire_staging = _pinned_host_sharding(self.state.atlas)
+        self._stage_wire = _stage_wire
 
     def init_state(self) -> StreamState:
         return StreamState(
@@ -241,7 +242,9 @@ class StreamingPipeline:
             wire, starts, stops, t_start, overflow = pack_wire(
                 px, py, pt, pp, bounds3, cap
             )
-            packed, valid = self._wire(*self._stage_wire(wire))
+            packed, valid = self._wire(
+                *self._stage_wire(wire, self._wire_staging)
+            )
             batch = EventBatch(
                 packed[0, 0], packed[1, 0], packed[2, 0], packed[3, 0],
                 valid[0],

@@ -282,18 +282,16 @@ def hint_wire(packed: jax.Array, valid: jax.Array, offsets: jax.Array):
 # ---------------------------------------------------------------------------
 
 def _current_axis_sizes() -> dict[str, int] | None:
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-    except Exception:
-        return None
-    if mesh is None or not getattr(mesh, "axis_names", ()):
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names:
         return None
     return dict(zip(mesh.axis_names, mesh.axis_sizes))
 
 
 def hint(x: jax.Array, *axes) -> jax.Array:
-    """with_sharding_constraint that degrades to identity when axes are
-    absent from the active mesh or do not divide the dim."""
+    """with_sharding_constraint over the active mesh: identity without
+    one, and axes absent from the mesh or not dividing the dim replicate.
+    A constraint the mesh cannot take raises."""
     sizes = _current_axis_sizes()
     if sizes is None:
         return x
@@ -311,7 +309,4 @@ def hint(x: jax.Array, *axes) -> jax.Array:
             spec.append(present if len(present) > 1 else present[0])
         else:
             spec.append(None)
-    try:
-        return jax.lax.with_sharding_constraint(x, P(*spec))
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(x, P(*spec))

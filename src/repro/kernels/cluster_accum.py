@@ -62,7 +62,12 @@ def _kernel(x_ref, y_ref, t_ref, valid_ref, out_ref, *, cell_size: int, grid_w: 
     stats = jnp.concatenate(
         [jnp.ones_like(xf), xf * v, yf * v, t * v], axis=0
     )  # (4, TILE); count row masked via onehot already
-    acc = jnp.dot(stats, onehot, preferred_element_type=jnp.float32)  # (4, CELLS)
+    # Full f32 passes: coordinates and timestamps must sum exactly, which a
+    # single bf16 MXU pass would round.
+    acc = jnp.dot(
+        stats, onehot, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )  # (4, CELLS)
     out_ref[...] += acc
 
 

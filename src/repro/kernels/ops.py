@@ -126,46 +126,45 @@ def patch_metrics_call(
     *,
     width: int = 640,
     height: int = 480,
-    window: int | None = None,
-    bins: int | None = None,
     interpret: bool | None = None,
 ) -> dict:
-    """Trace-time fused event->patch scatter + six cluster metrics.
+    """Trace-time six cluster metrics with the Pallas patch scatter.
 
-    Event-space preprocessing (coincidence counts, leaders, the frame
-    normalizer, patch origins) runs as jnp ops that fuse into the caller's
-    jit; the per-cluster patch accumulation, histogram, Sobel, and metric
-    math run in the Pallas kernel. Like :func:`cluster_accum_call` this is
-    safe inside an enclosing jit or scan body. Returns the metric dict
-    keyed by ``repro.core.metrics.METRIC_NAMES``.
+    The event-space metrics path
+    (:func:`repro.core.metrics.cluster_metrics_events`) with its
+    per-cluster count patches built by the ``patch_metrics`` kernel
+    instead of a scatter-add: the patches are the same exact
+    integers, and everything downstream is the shared jnp code, so the
+    metrics are bit-identical to the event path. Like
+    :func:`cluster_accum_call` this is safe inside an enclosing jit or
+    scan body. Returns the metric dict keyed by
+    ``repro.core.metrics.METRIC_NAMES``.
     """
     from repro.core import metrics as M
 
     interpret = _default_interpret() if interpret is None else interpret
-    window = M.WINDOW if window is None else window
-    bins = M.HIST_BINS if bins is None else bins
-    c, leader, w, norm = M.event_normalizer(batch, width, height)
-    x0, y0 = M.window_origin(
-        clusters.centroid_x, clusters.centroid_y, width, height, window
+
+    def count_patches(batch, clusters, width, height):
+        inb = (
+            (batch.x >= 0) & (batch.x < width)
+            & (batch.y >= 0) & (batch.y < height)
+        )
+        x0, y0 = M.window_origin(
+            clusters.centroid_x, clusters.centroid_y, width, height
+        )
+        n_pad = -(-batch.x.shape[0] // _pm.LANE) * _pm.LANE
+        return _pm.patch_counts(
+            _pad_to(batch.x.astype(jnp.int32), n_pad),
+            _pad_to(batch.y.astype(jnp.int32), n_pad),
+            _pad_to((batch.valid & inb).astype(jnp.float32), n_pad),
+            x0,
+            y0,
+            interpret=interpret,
+        )
+
+    return M.cluster_metrics_events(
+        batch, clusters, width, height, count_patches=count_patches
     )
-    e = batch.x.shape[0]
-    n_pad = -(-e // _pm.LANE) * _pm.LANE
-    out = _pm.patch_metrics(
-        _pad_to(batch.x.astype(jnp.int32), n_pad),
-        _pad_to(batch.y.astype(jnp.int32), n_pad),
-        _pad_to(w.astype(jnp.float32), n_pad),
-        _pad_to(c.astype(jnp.float32), n_pad),
-        _pad_to(leader.astype(jnp.float32), n_pad),
-        x0,
-        y0,
-        clusters.count,
-        clusters.valid,
-        norm,
-        window=window,
-        bins=bins,
-        interpret=interpret,
-    )
-    return {name: out[:, i] for i, name in enumerate(M.METRIC_NAMES)}
 
 
 def window_pipeline_call(

@@ -1,135 +1,92 @@
-"""Fused event->patch + six-metric Pallas kernel (beyond-paper).
+"""Event->patch scatter Pallas kernel for the metrics stage (beyond-paper).
 
 The paper's Discussion (Sec. VI) proposes pushing aggregation *and* the
-quality metrics into the fabric so the client only receives final
-statistics. This kernel realizes that for the metrics stage, the way
-``cluster_accum`` does for clustering (DESIGN.md Sec. 6): one program per
-cluster slot scatters the window's events into the cluster's 48x48
-centroid-relative count patch (one-hot compare + MXU matmul — the TPU
-idiom for the FPGA's BRAM scatter), builds the intensity histogram from
-per-event coincidence counts, runs the Sobel stencil, and emits all six
-quality metrics. No sensor-sized buffer exists anywhere: VMEM holds the
-event tile and one patch.
+quality metrics into the fabric. This kernel takes the heavy half of the
+metrics stage, the way ``cluster_accum`` does for clustering (DESIGN.md
+Sec. 6): one program per cluster slot scatters the window's events into
+the cluster's 48x48 centroid-relative count patch. The scatter is one
+(48, E) x (E, 48) MXU matmul of row and column one-hots — the TPU idiom
+for the FPGA's BRAM scatter — so no sensor-sized buffer exists anywhere:
+VMEM holds the event tile and one patch.
 
-The metric math is the shared exactly-replayable core
-(``repro.core.metrics._exact_cluster_metrics``), so kernel outputs match
-the jnp event-space path to float precision (interpret mode is exercised
-in CI; on TPU the one-hot matmuls land on the MXU).
+The kernel emits only exact integer count patches. Histogram counts, the
+Sobel stencil and the six metrics run in XLA through the same code the
+jnp event-space path runs (``repro.core.metrics.cluster_metrics_events``
+with this kernel as its patch builder), so the kernel route is
+bit-identical to the event path by construction, on any backend — the
+same split the fixed-point megakernel makes (``window_pipeline.py``).
 
 Inputs are per-event arrays padded to a lane multiple plus per-cluster
-patch origins; ``ops.patch_metrics_call`` handles layout and the
-event-space preprocessing (coincidence counts, leaders, normalizer).
+patch origins, which ride in SMEM; ``ops.patch_metrics_call`` handles
+layout.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import metrics as M
 
 LANE = 128
-N_METRICS = len(M.METRIC_NAMES)
+# Full f32 matmul passes: one-hot products must stay exact integers on the
+# MXU, never a rounded bf16 pass.
+EXACT = jax.lax.Precision.HIGHEST
 
 
-def _kernel(
-    x_ref, y_ref, w_ref, c_ref, lead_ref, x0_ref, y0_ref, count_ref,
-    cvalid_ref, norm_ref, out_ref, *, window: int, bins: int
-):
+def _kernel(x0_ref, y0_ref, x_ref, y_ref, w_ref, out_ref, *, window: int):
     e = x_ref.shape[-1]
-    npix = window * window
-    x = x_ref[...].astype(jnp.int32)  # (1, E)
-    y = y_ref[...].astype(jnp.int32)
-    w = w_ref[...]  # (1, E) f32 validity
-    c = c_ref[...]  # (1, E) f32 coincidence counts
-    lead = lead_ref[...]
-    norm = norm_ref[0, 0]
-    x0 = x0_ref[0, 0]
-    y0 = y0_ref[0, 0]
-
-    rx = x - x0
-    ry = y - y0
-    inp = w * (
-        (rx >= 0) & (rx < window) & (ry >= 0) & (ry < window)
-    ).astype(jnp.float32)  # (1, E)
-    flat = jnp.clip(ry, 0, window - 1) * window + jnp.clip(rx, 0, window - 1)
-
-    # Event -> patch scatter as a one-hot (E, npix) matmul.
-    cells = jax.lax.broadcasted_iota(jnp.int32, (e, npix), 1)
-    onehot = (flat.reshape(e, 1) == cells).astype(jnp.float32)
-    cnt_flat = jnp.dot(inp, onehot, preferred_element_type=jnp.float32)
-    cnt_patch = cnt_flat.reshape(window, window)
-
-    # Histogram straight from events: leaders carry their pixel's count.
-    bin_idx = jnp.clip((c / norm * bins).astype(jnp.int32), 0, bins - 1)
-    bins_iota = jax.lax.broadcasted_iota(jnp.int32, (e, bins), 1)
-    bins_onehot = (bin_idx.reshape(e, 1) == bins_iota).astype(jnp.float32)
-    lead_inp = inp * lead
-    hist = jnp.dot(lead_inp, bins_onehot, preferred_element_type=jnp.float32)
-    occ = jnp.sum(lead_inp)
-    hist = (hist + (jax.lax.broadcasted_iota(jnp.int32, (1, bins), 1) == 0)
-            * (npix - occ)).reshape(bins)
-
-    mets = M._exact_cluster_metrics(
-        cnt_patch, hist, norm, count_ref[0, 0], cvalid_ref[0, 0] > 0
+    k = pl.program_id(0)
+    rx = x_ref[...] - x0_ref[0, k]  # (1, E) int32
+    ry = y_ref[...] - y0_ref[0, k]
+    pix = jax.lax.broadcasted_iota(jnp.int32, (window, e), 0)
+    # patch[r, q] = sum_e w_e [ry_e == r] [rx_e == q]: events outside the
+    # patch match no row or column, so they drop out with no extra mask.
+    rows = (pix == ry).astype(jnp.float32) * w_ref[...]
+    cols = (pix == rx).astype(jnp.float32)
+    out_ref[...] = jax.lax.dot_general(
+        rows, cols, (((1,), (1,)), ((), ())),
+        precision=EXACT, preferred_element_type=jnp.float32,
     )
-    row = jnp.stack([mets[name] for name in M.METRIC_NAMES])
-    out_ref[...] = jnp.pad(row, (0, LANE - N_METRICS)).reshape(1, LANE)
 
 
-def patch_metrics(
+def patch_counts(
     x: jax.Array,
     y: jax.Array,
     w: jax.Array,
-    c: jax.Array,
-    leader: jax.Array,
     x0: jax.Array,
     y0: jax.Array,
-    count: jax.Array,
-    cvalid: jax.Array,
-    norm: jax.Array,
     *,
     window: int = M.WINDOW,
-    bins: int = M.HIST_BINS,
     interpret: bool = False,
 ) -> jax.Array:
-    """Six metrics for K cluster slots from one event window.
+    """(K, window, window) float32 event-count patches for K clusters.
 
-    Event arrays are (E,) with E a LANE multiple (ops.py pads, weight 0);
-    per-cluster arrays are (K,). Returns (K, N_METRICS) float32 in
-    ``METRIC_NAMES`` order. One grid step per cluster slot; the (E, 48^2)
-    one-hot block bounds VMEM use (~2.3 MB at E=256).
+    Event arrays are (E,) with E a LANE multiple (ops.py pads with weight
+    0); ``w`` is the per-event weight (validity); ``x0``/``y0`` are the
+    (K,) patch origins, staged in SMEM as (1, K) rows: a 2-D block stays
+    legal when ``vmap`` adds a batch dimension. One grid step per cluster
+    slot; the two (window, E) one-hot blocks bound VMEM use (~100 KB at
+    E=256).
     """
     e = x.shape[0]
     if e % LANE:
         raise ValueError(f"E ({e}) must be a multiple of {LANE}")
     k = x0.shape[0]
-
-    def ev(a, dtype):
-        return a.astype(dtype).reshape(1, e)
-
-    def per_cluster(a, dtype):
-        return a.astype(dtype).reshape(1, k)
-
     ev_spec = pl.BlockSpec((1, e), lambda i: (0, 0))
-    k_spec = pl.BlockSpec((1, 1), lambda i: (0, i))
-    out = pl.pallas_call(
-        lambda *refs: _kernel(*refs, window=window, bins=bins),
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    return pl.pallas_call(
+        lambda *refs: _kernel(*refs, window=window),
         grid=(k,),
-        in_specs=[ev_spec] * 5 + [k_spec] * 4 + [pl.BlockSpec((1, 1), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((1, LANE), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((k, LANE), jnp.float32),
+        in_specs=[smem, smem, ev_spec, ev_spec, ev_spec],
+        out_specs=pl.BlockSpec((None, window, window), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((k, window, window), jnp.float32),
         interpret=interpret,
     )(
-        ev(x, jnp.int32),
-        ev(y, jnp.int32),
-        ev(w, jnp.float32),
-        ev(c, jnp.float32),
-        ev(leader, jnp.float32),
-        per_cluster(x0, jnp.int32),
-        per_cluster(y0, jnp.int32),
-        per_cluster(count, jnp.float32),
-        per_cluster(cvalid, jnp.float32),
-        norm.astype(jnp.float32).reshape(1, 1),
+        x0.astype(jnp.int32).reshape(1, k),
+        y0.astype(jnp.int32).reshape(1, k),
+        x.astype(jnp.int32).reshape(1, e),
+        y.astype(jnp.int32).reshape(1, e),
+        w.astype(jnp.float32).reshape(1, e),
     )
-    return out[:, :N_METRICS]

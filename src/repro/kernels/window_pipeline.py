@@ -24,13 +24,18 @@ compares + MXU matmuls, the pairwise (E, E) same-pixel block replaces the
 sensor-sized histogram (exactly the event-space trick
 ``core.events.persistent_event_filter`` uses), and top-K is K unrolled
 (max, first-index, mask) passes — the same selection contract as
-``grid_clustering._top_k_cells``. Every one-hot f32 matmul produces the
-same exact integers the staged int32 scatters do (all sums stay below
-2^24). ``tests/test_fixed_point.py`` pins the identity over randomized
-and adversarial windows.
+``grid_clustering._top_k_cells``. Each cluster's 48x48 count patch is
+one (48, E) x (E, 48) matmul of row and column one-hots, so no flat
+patch is ever reshaped across lanes. Every one-hot matmul runs at full
+f32 precision (``EXACT``) and produces the same exact integers the
+staged int32 scatters do (all sums stay below 2^24).
+``tests/test_fixed_point.py`` pins the identity over randomized and
+adversarial windows.
 
-Layout: inputs are (W, E) int32 event arrays (E a LANE multiple,
-wrapper-padded); outputs are one (W, CL_ROWS, LANE) int32 block of
+Layout: ``window_pipeline`` takes (W, E) int32 event arrays (E a LANE
+multiple, wrapper-padded) and hands the kernel (W, 1, E) with a
+(None, 1, E) block, which the TPU's (8, 128) tiling rule admits; outputs
+are one (W, CL_ROWS, LANE) int32 block of
 cluster fields (cluster slot k in lane k; row ``CL_FIELDS.index(f)`` =
 field f; row 9 carries the per-window frame normalizer) and one
 (W, K, LANE) int32 block of per-cluster surfaces (row k = cluster k:
@@ -46,6 +51,9 @@ from repro.core import fixed_point as FX
 from repro.core import metrics as M
 
 LANE = 128
+# Full f32 matmul passes: the one-hot products carry coordinates and
+# timestamps and must stay exact integers, never a rounded bf16 pass.
+EXACT = jax.lax.Precision.HIGHEST
 CL_ROWS = 16
 CL_FIELDS = (
     "count", "cell_x", "cell_y", "cq_x", "cq_y", "cq_t", "x0", "y0",
@@ -117,7 +125,7 @@ def _kernel(
     # Exact: every per-cell sum is an integer below 2^24 (count <= E,
     # sum_x < E * width, sum_t < E * time_threshold).
     cell_stats = jnp.dot(
-        stats, cell_onehot, preferred_element_type=jnp.float32
+        stats, cell_onehot, precision=EXACT, preferred_element_type=jnp.float32
     ).astype(jnp.int32)  # (4, C_pad)
     counts = cell_stats[0:1, :]  # padded cells hold count 0
 
@@ -171,7 +179,7 @@ def _kernel(
     bin_idx = jnp.clip((c * bins) // norm_i, 0, bins - 1)
     bins_iota = jax.lax.broadcasted_iota(jnp.int32, (e, bins), 1)
     bins_onehot = (bin_idx.reshape(e, 1) == bins_iota).astype(jnp.float32)
-    pix_iota = jax.lax.broadcasted_iota(jnp.int32, (e, npix), 1)
+    pix = jax.lax.broadcasted_iota(jnp.int32, (window, e), 0)
     leadf = leader.astype(jnp.float32)
     rowk = jax.lax.broadcasted_iota(jnp.int32, (k, 1), 0)
 
@@ -187,16 +195,19 @@ def _kernel(
         inp = (
             (rx >= 0) & (rx < window) & (ry >= 0) & (ry < window) & w
         ).astype(jnp.float32)  # (1, E)
-        pflat = (
-            jnp.clip(ry, 0, window - 1) * window + jnp.clip(rx, 0, window - 1)
-        )
-        pix_onehot = (pflat.reshape(e, 1) == pix_iota).astype(jnp.float32)
-        cnt_flat = jnp.dot(inp, pix_onehot, preferred_element_type=jnp.float32)
-        patch = cnt_flat.reshape(window, window).astype(jnp.int32)
+        # patch[r, q] = sum_e inp_e [ry_e == r] [rx_e == q]: one
+        # (window, E) x (E, window) matmul of row and column one-hots.
+        rows = (pix == ry).astype(jnp.float32) * inp
+        cols = (pix == rx).astype(jnp.float32)
+        patch = jax.lax.dot_general(
+            rows, cols, (((1,), (1,)), ((), ())),
+            precision=EXACT, preferred_element_type=jnp.float32,
+        ).astype(jnp.int32)  # (window, window)
 
         lead_inp = inp * leadf
         hist = jnp.dot(
-            lead_inp, bins_onehot, preferred_element_type=jnp.float32
+            lead_inp, bins_onehot, precision=EXACT,
+            preferred_element_type=jnp.float32,
         )  # (1, bins)
         occ = jnp.sum(lead_inp)
         hist = hist + (
@@ -223,8 +234,8 @@ def _kernel(
         0, k, per_cluster, jnp.zeros((k, LANE), jnp.int32)
     )
 
-    cl_ref[...] = cl.reshape(1, CL_ROWS, LANE)
-    surf_ref[...] = surf.reshape(1, k, LANE)
+    cl_ref[...] = cl
+    surf_ref[...] = surf
 
 
 def window_pipeline(
@@ -266,7 +277,7 @@ def window_pipeline(
     if bins + len(SURF_FIELDS) > LANE:
         raise ValueError(f"bins ({bins}) too large for the surface row")
 
-    ev_spec = pl.BlockSpec((1, e), lambda i: (i, 0))
+    ev_spec = pl.BlockSpec((None, 1, e), lambda i: (i, 0, 0))
     kernel = lambda *refs: _kernel(  # noqa: E731
         *refs,
         roi=roi, hot_pixel_max=hot_pixel_max, cell_size=cell_size,
@@ -278,17 +289,15 @@ def window_pipeline(
         grid=(n_windows,),
         in_specs=[ev_spec] * 4,
         out_specs=[
-            pl.BlockSpec((1, CL_ROWS, LANE), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, k, LANE), lambda i: (i, 0, 0)),
+            pl.BlockSpec((None, CL_ROWS, LANE), lambda i: (i, 0, 0)),
+            pl.BlockSpec((None, k, LANE), lambda i: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n_windows, CL_ROWS, LANE), jnp.int32),
             jax.ShapeDtypeStruct((n_windows, k, LANE), jnp.int32),
         ],
         interpret=interpret,
-    )(
-        x.astype(jnp.int32),
-        y.astype(jnp.int32),
-        t.astype(jnp.int32),
-        valid.astype(jnp.int32),
-    )
+    )(*(
+        a.astype(jnp.int32).reshape(n_windows, 1, e)
+        for a in (x, y, t, valid)
+    ))

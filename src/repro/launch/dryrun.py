@@ -28,7 +28,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs.base import LM_SHAPES, ModelConfig, applicable_shapes, get_config, list_archs
 from repro.distributed import sharding as S
 from repro.launch import roofline as R
-from repro.launch.mesh import make_production_mesh, use_mesh
+from repro.launch.mesh import TARGET_DEVICE_KIND, make_production_mesh
 from repro.models.transformer import decode_step, init_cache, init_params, prefill
 from repro.train.optimizer import init_opt_state
 from repro.train.train_step import TrainConfig, make_train_step
@@ -274,7 +274,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: Path,
     t0 = time.time()
     try:
         fn, args, in_shardings, out_shardings, donate = build_cell(cfg, shape_name, mesh, variant)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             jitted = jax.jit(
                 fn,
                 in_shardings=in_shardings,
@@ -292,7 +292,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: Path,
             k: v for k, v in (cost or {}).items() if k in ("flops", "bytes accessed")
         }
         print(raw_cost)
-        terms = R.extract_terms(compiled, n_dev)
+        terms = R.extract_terms(compiled, n_dev, TARGET_DEVICE_KIND)
         spec = LM_SHAPES[shape_name]
         tokens = spec.global_batch * (spec.seq_len if spec.kind != "decode" else 1)
         mf = R.model_flops(

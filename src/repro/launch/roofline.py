@@ -18,7 +18,7 @@ from __future__ import annotations
 import dataclasses
 
 from repro.launch.hlo_analysis import analyze
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import chip_peaks
 
 
 @dataclasses.dataclass
@@ -27,19 +27,20 @@ class RooflineTerms:
     hbm_bytes: float  # per-device bytes moved
     coll_bytes: float  # per-device collective payload bytes
     n_devices: int
+    device_kind: str  # keys the peak table; an unknown chip raises
     coll_breakdown: dict = dataclasses.field(default_factory=dict)
 
     @property
     def t_compute(self) -> float:
-        return self.flops / PEAK_FLOPS_BF16
+        return self.flops / chip_peaks(self.device_kind).flops_bf16
 
     @property
     def t_memory(self) -> float:
-        return self.hbm_bytes / HBM_BW
+        return self.hbm_bytes / chip_peaks(self.device_kind).hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return self.coll_bytes / ICI_BW
+        return self.coll_bytes / chip_peaks(self.device_kind).ici_bw
 
     @property
     def bottleneck(self) -> str:
@@ -60,6 +61,7 @@ class RooflineTerms:
             "hbm_bytes_per_device": self.hbm_bytes,
             "coll_bytes_per_device": self.coll_bytes,
             "n_devices": self.n_devices,
+            "device_kind": self.device_kind,
             "t_compute_s": self.t_compute,
             "t_memory_s": self.t_memory,
             "t_collective_s": self.t_collective,
@@ -68,14 +70,16 @@ class RooflineTerms:
         }
 
 
-def extract_terms(compiled, n_devices: int) -> RooflineTerms:
-    """Pull per-device roofline terms from a compiled artifact's HLO."""
+def extract_terms(compiled, n_devices: int, device_kind: str) -> RooflineTerms:
+    """Pull per-device roofline terms from a compiled artifact's HLO,
+    against the peaks of ``device_kind``."""
     stats = analyze(compiled.as_text())
     return RooflineTerms(
         flops=stats["flops"],
         hbm_bytes=stats["bytes"],
         coll_bytes=stats["coll_bytes"],
         n_devices=n_devices,
+        device_kind=device_kind,
         coll_breakdown=stats["coll_breakdown"],
     )
 

@@ -248,6 +248,12 @@ class DetectionService:
         return self._fleet.wire_stats
 
     @property
+    def atlas(self):
+        """The fleet's stacked event-atlas carry; ``.devices()`` says
+        which chip(s) hold this service's stream state."""
+        return self._fleet.state.atlas
+
+    @property
     def n_sessions(self) -> int:
         """Live (attached) sessions."""
         return len(self._by_slot)

@@ -10,7 +10,8 @@ tests import the modules the way ``benchmarks.run`` does and pin:
   rows with sane magnitudes, and its ``bench()`` degrades to the
   ``roofline/missing`` row when no dryrun records exist,
 * the ``benchmarks.run`` aggregator survives a gated bench that writes
-  no ``BENCH_*.json`` (ERROR row + exit 1) and rejects unknown keys.
+  no ``BENCH_*.json`` (ERROR row + exit 1), fails when a table module
+  raises, and rejects unknown keys.
 """
 import sys
 from pathlib import Path
@@ -84,6 +85,17 @@ def test_run_aggregator_missing_bench_json(monkeypatch, capsys):
     assert exc.value.code == 1
     out = capsys.readouterr().out
     assert "ERROR (no BENCH json)" in out
+
+
+def test_run_aggregator_fails_when_a_module_raises(monkeypatch):
+    # A table module that raises must fail the run, not print an ERROR
+    # row and exit 0.
+    monkeypatch.setattr(run, "MODULES", {"ghost": "benchmarks.no_such_module"})
+    monkeypatch.setattr(sys, "argv", ["run.py", "ghost"])
+    with pytest.raises(SystemExit) as exc:
+        run.main()
+    assert exc.value.code not in (0, None)
+    assert "ghost" in str(exc.value.code)
 
 
 def test_run_aggregator_rejects_unknown_key(monkeypatch):

@@ -46,7 +46,7 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core.events import EventBatch
 from repro.core.grid_clustering import GridConfig, grid_cluster
-from repro.launch.mesh import make_mesh, shard_map
+from repro.launch.mesh import make_mesh
 
 nodes, windows, cap = 4, 8, 256
 mesh = make_mesh((nodes,), ("node",))
@@ -66,7 +66,7 @@ def node_fn(b):
     out = jax.vmap(lambda eb: grid_cluster(eb, grid).count)(b)
     return out[None]  # re-add for out_specs P("node")
 
-fn = jax.jit(shard_map(
+fn = jax.jit(jax.shard_map(
     node_fn, mesh=mesh,
     in_specs=(jax.tree.map(lambda _: P("node"), batch),), out_specs=P("node")))
 counts = np.asarray(fn(batch))

@@ -149,7 +149,7 @@ import dataclasses, jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs.base import get_config
 from repro.distributed import sharding as S
-from repro.launch.mesh import make_mesh, use_mesh
+from repro.launch.mesh import make_mesh
 from repro.launch.dryrun import _batch_sharding, _cache_sharding
 from repro.models.transformer import init_params, init_cache, decode_step
 from repro.train.optimizer import init_opt_state
@@ -167,7 +167,7 @@ pspec = S.partition_params(params_sds, rules, mesh)
 pshard = jax.tree.map(lambda s: NamedSharding(mesh, s), pspec)
 
 kind = {shape_kind!r}
-with use_mesh(mesh):
+with jax.set_mesh(mesh):
     if kind == "train":
         batch = {{
             "tokens": jax.ShapeDtypeStruct((8, 64), jnp.int32),

@@ -220,11 +220,11 @@ def test_ring_allreduce_int8_matches_mean(subproc):
 import jax, jax.numpy as jnp, numpy as np
 from functools import partial
 from jax.sharding import PartitionSpec as P
-from repro.launch.mesh import make_mesh, shard_map
+from repro.launch.mesh import make_mesh
 from repro.distributed.compression import ring_allreduce_int8
 mesh = make_mesh((4,), ("dp",))
 x = np.random.default_rng(0).normal(size=(4, 128)).astype(np.float32)
-fn = shard_map(
+fn = jax.shard_map(
     partial(ring_allreduce_int8, axis_name="dp", axis_size=4),
     mesh=mesh, in_specs=P("dp"), out_specs=P("dp"),
 )
