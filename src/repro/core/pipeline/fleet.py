@@ -63,6 +63,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.events import (
     SPILL_QUANTUM,
@@ -200,19 +201,21 @@ def make_fleet_fn(config: PipelineConfig = PipelineConfig(), with_tracking: bool
         atlas = hint_fleet(atlas)
         state = hint_fleet(state)
         stacked = hint_fleet(stacked)
-        _, clusters, mets, states, atlas = vcore(stacked, state, atlas, tag0)
-        if uniform:
-            final = jax.tree.map(lambda per_w: per_w[:, -1], states)
-        else:
-            s_ix = jnp.arange(n_valid.shape[0])
-            last = jnp.maximum(n_valid - 1, 0)
-            final = jax.tree.map(
-                lambda per_w, prev: jnp.where(
-                    (n_valid > 0)[:, None], per_w[s_ix, last], prev
-                ),
-                states,
-                state,
-            )
+        with jax.named_scope("window_core"):
+            _, clusters, mets, states, atlas = vcore(stacked, state, atlas, tag0)
+        with jax.named_scope("carry_reselect"):
+            if uniform:
+                final = jax.tree.map(lambda per_w: per_w[:, -1], states)
+            else:
+                s_ix = jnp.arange(n_valid.shape[0])
+                last = jnp.maximum(n_valid - 1, 0)
+                final = jax.tree.map(
+                    lambda per_w, prev: jnp.where(
+                        (n_valid > 0)[:, None], per_w[s_ix, last], prev
+                    ),
+                    states,
+                    state,
+                )
         return final, clusters, mets, states, hint_fleet(atlas)
 
     return jax.jit(step, donate_argnums=(3,), static_argnums=(5,))
@@ -252,9 +255,10 @@ def make_wire_fn(capacity: int, use_kernels: bool):
         words, dt16, pol, offsets, spill = jax.device_put(
             (words, dt16, pol, offsets, spill), jax.memory.Space.Device
         )
-        packed, valid = unpack_wire(
-            words, dt16, pol, offsets, spill, capacity, unpack_impl
-        )
+        with jax.named_scope("unpack_wire"):
+            packed, valid = unpack_wire(
+                words, dt16, pol, offsets, spill, capacity, unpack_impl
+            )
         packed, valid, _ = hint_wire(packed, valid, offsets)
         return packed, valid
 
@@ -300,13 +304,17 @@ def _stage_wire(views: tuple, sharding) -> tuple:
 
 @dataclasses.dataclass
 class WireStats:
-    """Host->device ingest transfer accounting, accumulated per round.
+    """Host<->device transfer accounting, accumulated per round.
 
     ``wire_bytes`` counts what the active wire mode actually ships;
     ``dense_bytes`` is the dense-equivalent cost of the same rounds
     (identical, by construction, when ``wire="dense"``), so
     ``compression`` is the measured transfer reduction the ragged wire
-    delivers at the workload's real occupancy.
+    delivers at the workload's real occupancy. ``d2h_transfers`` and
+    ``d2h_bytes`` count the copy-back: one transfer per stacked output
+    leaf a round's first host read brings over, and those leaves' bytes
+    (:meth:`FleetResult._host_view`; on the CPU backend the same leaves
+    are host views, counted alike).
     """
 
     rounds: int = 0
@@ -314,6 +322,8 @@ class WireStats:
     wire_bytes: int = 0
     dense_bytes: int = 0
     spilled: int = 0  # events that took the exact int32 spill lane
+    d2h_transfers: int = 0  # output leaves copied back to the host
+    d2h_bytes: int = 0  # bytes of those leaves
 
     @property
     def compression(self) -> float:
@@ -330,6 +340,8 @@ class WireStats:
         self.wire_bytes += other.wire_bytes
         self.dense_bytes += other.dense_bytes
         self.spilled += other.spilled
+        self.d2h_transfers += other.d2h_transfers
+        self.d2h_bytes += other.d2h_bytes
 
 
 @functools.lru_cache(maxsize=None)
@@ -423,6 +435,8 @@ class FleetResult:
     _carry_tracks: TrackState  # (S, T) carry after this feed (empty-feed path)
     _host: tuple | None = None  # numpy copy of the stacked leaves, on demand
     _hot_rows: dict | None = None  # slot -> row into the gathered host leaves
+    _stats: WireStats | None = None  # the fleet's; counts the copy-back
+    _round: int = -1  # the fleet's round number (``WireStats.rounds``)
 
     @property
     def n_sensors(self) -> int:
@@ -454,41 +468,49 @@ class FleetResult:
         included — so it always crosses in full.
         """
         if self._host is None:
-            s_count = len(self.windows)
-            hot = np.flatnonzero(np.asarray(self.n_windows) > 0)
-            if 2 * len(hot) >= s_count:
-                # Mostly-hot fleet: plain per-leaf transfers beat the
-                # extra gather dispatch per leaf.
-                self._host = jax.tree.map(
-                    np.asarray,
-                    (self.clusters, self.metrics, self.tracks,
-                     self.final_tracks),
-                )
-                self._hot_rows = None
-            else:
-                if jax.default_backend() == "cpu":
-                    # Host memory IS device memory: np.asarray is a
-                    # zero-copy view, so "transfer only the hot rows"
-                    # means one numpy fancy-index per leaf (copies just
-                    # those rows, and releases the full (S, W, ...)
-                    # stacked buffers a long-held view would pin). A
-                    # device-side gather here would cost a dispatched
-                    # computation per leaf — measured ~90x the full view
-                    # in benchmarks/serve_latency.py.
-                    gather = lambda a: np.asarray(a)[hot]
-                else:
-                    # Separate device memory: gather on device so only
-                    # the valid-window rows cross the wire.
-                    idx = jnp.asarray(hot)
-                    gather = lambda a: np.asarray(a[idx])
-                self._host = (
-                    jax.tree.map(gather, self.clusters),
-                    jax.tree.map(gather, self.metrics),
-                    jax.tree.map(gather, self.tracks),
-                    jax.tree.map(np.asarray, self.final_tracks),
-                )
-                self._hot_rows = {int(s): i for i, s in enumerate(hot)}
+            # The wait for the round and the transfers are timed apart:
+            # the first leaf read would otherwise hide the wait.
+            with TraceAnnotation("fleet.copy_wait", round=self._round):
+                self.block_until_ready()
+            with TraceAnnotation("fleet.copy_back", round=self._round):
+                self._host, self._hot_rows = self._copy_back()
+            if self._stats is not None:
+                leaves = jax.tree.leaves(self._host)
+                self._stats.d2h_transfers += len(leaves)
+                self._stats.d2h_bytes += sum(a.nbytes for a in leaves)
         return self._host
+
+    def _copy_back(self) -> tuple[tuple, dict | None]:
+        """The stacked leaves on the host, and the hot-row map (``None``
+        on the full path); see :meth:`_host_view`."""
+        s_count = len(self.windows)
+        hot = np.flatnonzero(np.asarray(self.n_windows) > 0)
+        leaves = (self.clusters, self.metrics, self.tracks, self.final_tracks)
+        if 2 * len(hot) >= s_count:
+            # Mostly-hot fleet: plain per-leaf transfers beat the extra
+            # gather dispatch per leaf.
+            return jax.tree.map(np.asarray, leaves), None
+        if jax.default_backend() == "cpu":
+            # Host memory IS device memory: np.asarray is a zero-copy
+            # view, so "transfer only the hot rows" means one numpy
+            # fancy-index per leaf (copies just those rows, and releases
+            # the full (S, W, ...) stacked buffers a long-held view would
+            # pin). A device-side gather here would cost a dispatched
+            # computation per leaf — measured ~90x the full view in
+            # benchmarks/serve_latency.py.
+            gather = lambda a: np.asarray(a)[hot]
+        else:
+            # Separate device memory: gather on device so only the
+            # valid-window rows cross the wire.
+            idx = jnp.asarray(hot)
+            gather = lambda a: np.asarray(a[idx])
+        host = (
+            jax.tree.map(gather, self.clusters),
+            jax.tree.map(gather, self.metrics),
+            jax.tree.map(gather, self.tracks),
+            jax.tree.map(np.asarray, self.final_tracks),
+        )
+        return host, {int(s): i for i, s in enumerate(hot)}
 
     def ready(self) -> bool:
         """True when the device step behind this feed has completed (its
@@ -646,7 +668,10 @@ class _StagingPool:
         # (s, w, cap, wire) -> [ix, sets]
         self._rings: dict[tuple[int, int, int, str], list] = {}
 
-    def acquire(self, s: int, w: int, cap: int, wire: str = "dense"):
+    def acquire(self, s: int, w: int, cap: int, wire: str = "dense",
+                round_ix: int = -1):
+        """A staging set for shape ``(s, w, cap)``; ``round_ix`` (the
+        round it will carry) tags the span of a wait for its borrower."""
         key = (s, w, cap, wire)
         ring = self._rings.pop(key, None)
         if ring is None:
@@ -659,7 +684,8 @@ class _StagingPool:
         ring[0] = (ix + 1) % self.depth
         st = sets[ix]
         if st.inflight is not None:
-            st.inflight.wait()
+            with TraceAnnotation("fleet.staging_wait", round=round_ix):
+                st.inflight.wait()
             st.inflight = None
         if wire == "dense":
             st.packed.fill(0)
@@ -974,22 +1000,27 @@ class FleetPipeline:
                     f"final mask must have shape ({s_count},), got {final.shape}"
                 )
         batcher = self.config.batcher
+        # The round this feed dispatches, on every span it records; host
+        # spans split ``feed``'s time into windowing, waiting for a staging
+        # set, packing, staging and dispatch (a compile lands in dispatch).
+        rnd = self.wire_stats.rounds
         merged_all, bounds_all, consumed_all = [], [], []
         # Phase A (fallible): validate + window every sensor BEFORE any
         # state mutation, so a bad chunk rejects the whole feed atomically.
-        for s, (cur, chunk) in enumerate(zip(st.cursors, chunks)):
-            x, y, t, p = _EMPTY_CHUNK if chunk is None else chunk
-            merged = monotone_merge(
-                cur.pending, x, y, t, p, cur.last_t, label=f"sensor {s}"
-            )
-            if final[s]:
-                bounds = dual_threshold_bounds(merged[2], batcher)
-                consumed = len(merged[2])
-            else:
-                bounds, consumed = dual_threshold_closed_bounds(merged[2], batcher)
-            merged_all.append(merged)
-            bounds_all.append(bounds)
-            consumed_all.append(consumed)
+        with TraceAnnotation("fleet.window", round=rnd):
+            for s, (cur, chunk) in enumerate(zip(st.cursors, chunks)):
+                x, y, t, p = _EMPTY_CHUNK if chunk is None else chunk
+                merged = monotone_merge(
+                    cur.pending, x, y, t, p, cur.last_t, label=f"sensor {s}"
+                )
+                if final[s]:
+                    bounds = dual_threshold_bounds(merged[2], batcher)
+                    consumed = len(merged[2])
+                else:
+                    bounds, consumed = dual_threshold_closed_bounds(merged[2], batcher)
+                merged_all.append(merged)
+                bounds_all.append(bounds)
+                consumed_all.append(consumed)
         n_valid = np.asarray([len(b) for b in bounds_all], np.int32)
         w_max = int(n_valid.max())
         if w_max > self._tag_limit:
@@ -1007,7 +1038,9 @@ class FleetPipeline:
         cap = batcher.capacity
         ragged = self.wire == "ragged"
         staging = (
-            self._staging.acquire(s_count, w_max, cap, wire=self.wire)
+            self._staging.acquire(
+                s_count, w_max, cap, wire=self.wire, round_ix=rnd
+            )
             if w_max
             else None
         )
@@ -1022,71 +1055,72 @@ class FleetPipeline:
         tag0 = np.zeros(s_count, np.int32)
         reset = np.zeros(s_count, bool)
         windows_list: list[WindowedEvents] = []
-        for s, (cur, merged, bounds, consumed) in enumerate(
-            zip(st.cursors, merged_all, bounds_all, consumed_all)
-        ):
-            mt = merged[2]
-            bounds3 = [(a, b, int(mt[a])) for a, b in bounds]
-            if staging is None:
-                starts = stops = t_start = np.zeros(0, np.int64)
-                overflow = np.zeros(0, np.int64)
-                zeros = np.zeros((0, cap), np.int32)
-                row = EventBatch(
-                    zeros, zeros, zeros, zeros, np.zeros((0, cap), bool)
-                )
-            elif ragged:
-                starts, stops, t_start, overflow, wire_base, entries = (
-                    pack_bounds_into(
-                        *merged, bounds3,
-                        out=(staging.words, staging.dt, staging.pbits,
-                             staging.offsets[s]),
-                        layout="ragged", base=wire_base, capacity=cap,
+        with TraceAnnotation("fleet.pack", round=rnd):
+            for s, (cur, merged, bounds, consumed) in enumerate(
+                zip(st.cursors, merged_all, bounds_all, consumed_all)
+            ):
+                mt = merged[2]
+                bounds3 = [(a, b, int(mt[a])) for a, b in bounds]
+                if staging is None:
+                    starts = stops = t_start = np.zeros(0, np.int64)
+                    overflow = np.zeros(0, np.int64)
+                    zeros = np.zeros((0, cap), np.int32)
+                    row = EventBatch(
+                        zeros, zeros, zeros, zeros, np.zeros((0, cap), bool)
+                    )
+                elif ragged:
+                    starts, stops, t_start, overflow, wire_base, entries = (
+                        pack_bounds_into(
+                            *merged, bounds3,
+                            out=(staging.words, staging.dt, staging.pbits,
+                                 staging.offsets[s]),
+                            layout="ragged", base=wire_base, capacity=cap,
+                        )
+                    )
+                    if entries.shape[1]:
+                        spill_blocks.append(entries)
+                    n = len(bounds)
+                    # Bookkeeping rows are fresh dense planes (the ragged
+                    # wire has no per-window rows to copy out): same packer,
+                    # same bits, and like the dense path's copies they stay
+                    # stable for the round's lifetime.
+                    rx = np.zeros((n, cap), np.int32)
+                    ry = np.zeros((n, cap), np.int32)
+                    rt = np.zeros((n, cap), np.int32)
+                    rp = np.zeros((n, cap), np.int32)
+                    rv = np.zeros((n, cap), bool)
+                    if n:
+                        pack_bounds_into(*merged, bounds3, rx, ry, rt, rp, rv)
+                    row = EventBatch(rx, ry, rt, rp, rv)
+                else:
+                    starts, stops, t_start, overflow = pack_bounds_into(
+                        *merged, bounds3, out=(bx[s], by[s], bt[s], bp[s], bv[s])
+                    )
+                    n = len(bounds)
+                    # Per-sensor bookkeeping rows are COPIES of the packed
+                    # rows, not views: the staging planes are refilled two
+                    # rounds later, but the WindowedEvents a caller holds
+                    # must stay stable for the round's lifetime.
+                    row = EventBatch(
+                        bx[s, :n].copy(), by[s, :n].copy(), bt[s, :n].copy(),
+                        bp[s, :n].copy(), bv[s, :n].copy(),
+                    )
+                events_total += int(np.minimum(stops - starts, cap).sum())
+                n = len(bounds)
+                base = cur.events_consumed
+                windows_list.append(
+                    WindowedEvents(
+                        row, t_start, starts + base, stops + base, overflow
                     )
                 )
-                if entries.shape[1]:
-                    spill_blocks.append(entries)
-                n = len(bounds)
-                # Bookkeeping rows are fresh dense planes (the ragged
-                # wire has no per-window rows to copy out): same packer,
-                # same bits, and like the dense path's copies they stay
-                # stable for the round's lifetime.
-                rx = np.zeros((n, cap), np.int32)
-                ry = np.zeros((n, cap), np.int32)
-                rt = np.zeros((n, cap), np.int32)
-                rp = np.zeros((n, cap), np.int32)
-                rv = np.zeros((n, cap), bool)
-                if n:
-                    pack_bounds_into(*merged, bounds3, rx, ry, rt, rp, rv)
-                row = EventBatch(rx, ry, rt, rp, rv)
-            else:
-                starts, stops, t_start, overflow = pack_bounds_into(
-                    *merged, bounds3, out=(bx[s], by[s], bt[s], bp[s], bv[s])
-                )
-                n = len(bounds)
-                # Per-sensor bookkeeping rows are COPIES of the packed
-                # rows, not views: the staging planes are refilled two
-                # rounds later, but the WindowedEvents a caller holds
-                # must stay stable for the round's lifetime.
-                row = EventBatch(
-                    bx[s, :n].copy(), by[s, :n].copy(), bt[s, :n].copy(),
-                    bp[s, :n].copy(), bv[s, :n].copy(),
-                )
-            events_total += int(np.minimum(stops - starts, cap).sum())
-            n = len(bounds)
-            base = cur.events_consumed
-            windows_list.append(
-                WindowedEvents(
-                    row, t_start, starts + base, stops + base, overflow
-                )
-            )
-            t0 = cur.next_tag
-            if t0 + w_max > self._tag_limit:  # tag epoch rollover
-                reset[s], t0 = True, 0
-            tag0[s] = t0
-            cur.pending = tuple(a[consumed:] for a in merged)
-            cur.events_consumed = base + consumed
-            cur.next_tag = t0 + n
-            cur.last_t = int(mt[-1]) if len(mt) else cur.last_t
+                t0 = cur.next_tag
+                if t0 + w_max > self._tag_limit:  # tag epoch rollover
+                    reset[s], t0 = True, 0
+                tag0[s] = t0
+                cur.pending = tuple(a[consumed:] for a in merged)
+                cur.events_consumed = base + consumed
+                cur.next_tag = t0 + n
+                cur.last_t = int(mt[-1]) if len(mt) else cur.last_t
 
         if w_max == 0:
             return PendingRound(FleetResult(
@@ -1098,37 +1132,38 @@ class FleetPipeline:
                 _carry_tracks=st.tracks,
             ))
 
-        staging.meta[0] = tag0
-        staging.meta[1] = n_valid
-        if ragged:
-            n_pad = wire_pad(wire_base)
-            m = 0
-            if spill_blocks:
-                entries = np.concatenate(spill_blocks, axis=1)
-                m = entries.shape[1]
-            m_pad = spill_pad(m)
-            staging.reserve_spill(m_pad)
-            # Re-sentinel the whole view EVERY round: a stale spill entry
-            # from a previous borrower points at live wire positions and
-            # would overwrite real events in the decoder's scatter.
-            staging.spill[:, :m_pad] = SPILL_SENTINEL
-            if m:
-                staging.spill[:, :m] = entries
-                self.wire_stats.spilled += m
-            if wire_base:
-                packed_bits = np.packbits(
-                    staging.pbits[:wire_base], bitorder="little"
-                )
-                staging.pol.view(np.uint8)[: len(packed_bits)] = packed_bits
-            views = _stage_wire((
-                staging.words[:n_pad], staging.dt[:n_pad],
-                staging.pol[: n_pad // 32], staging.offsets,
-                staging.spill[:, :m_pad],
-            ), self._wire_staging)
-            wire_b = ragged_wire_bytes(n_pad, s_count, w_max, m_pad)
-        else:
-            wire_b = dense_wire_bytes(s_count, w_max, cap)
-        with self._mesh_ctx():
+        with TraceAnnotation("fleet.stage", round=rnd):
+            staging.meta[0] = tag0
+            staging.meta[1] = n_valid
+            if ragged:
+                n_pad = wire_pad(wire_base)
+                m = 0
+                if spill_blocks:
+                    entries = np.concatenate(spill_blocks, axis=1)
+                    m = entries.shape[1]
+                m_pad = spill_pad(m)
+                staging.reserve_spill(m_pad)
+                # Re-sentinel the whole view EVERY round: a stale spill entry
+                # from a previous borrower points at live wire positions and
+                # would overwrite real events in the decoder's scatter.
+                staging.spill[:, :m_pad] = SPILL_SENTINEL
+                if m:
+                    staging.spill[:, :m] = entries
+                    self.wire_stats.spilled += m
+                if wire_base:
+                    packed_bits = np.packbits(
+                        staging.pbits[:wire_base], bitorder="little"
+                    )
+                    staging.pol.view(np.uint8)[: len(packed_bits)] = packed_bits
+                views = _stage_wire((
+                    staging.words[:n_pad], staging.dt[:n_pad],
+                    staging.pol[: n_pad // 32], staging.offsets,
+                    staging.spill[:, :m_pad],
+                ), self._wire_staging)
+                wire_b = ragged_wire_bytes(n_pad, s_count, w_max, m_pad)
+            else:
+                wire_b = dense_wire_bytes(s_count, w_max, cap)
+        with TraceAnnotation("fleet.dispatch", round=rnd), self._mesh_ctx():
             atlas_in = st.atlas
             if reset.any():  # rare: tag-epoch rollover on some sensor(s)
                 atlas_in = _zero_sensors_fn()(atlas_in, jnp.asarray(reset))
@@ -1157,6 +1192,8 @@ class FleetPipeline:
             _config=self.config,
             _with_tracking=self.with_tracking,
             _carry_tracks=final_tracks,
+            _stats=self.wire_stats,
+            _round=rnd,
         ))
         staging.inflight = pending
         return pending

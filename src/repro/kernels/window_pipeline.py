@@ -286,6 +286,7 @@ def window_pipeline(
     )
     return pl.pallas_call(
         kernel,
+        name="window_pipeline",
         grid=(n_windows,),
         in_specs=[ev_spec] * 4,
         out_specs=[

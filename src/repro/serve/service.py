@@ -49,6 +49,7 @@ import time
 from typing import Callable
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.pipeline.config import PipelineConfig
 from repro.core.pipeline.fleet import (
@@ -84,6 +85,12 @@ class ServedFeed:
     one sync, not one per round — the pipelined-ingest contract
     (DESIGN.md Sec. 14). Everything else (``sid``, ``latency_ms``,
     ``num_windows``) is host data, readable without blocking.
+
+    ``latency_ms`` is queue wait: from the oldest queued chunk's arrival
+    to the round's dispatch, known when the round is dispatched. The
+    time from that arrival to the result on the host is recorded, on
+    the service's clock, into the session's
+    ``SessionStats.result_latency_ms`` when ``result`` is first read.
     """
 
     sid: int
@@ -91,6 +98,9 @@ class ServedFeed:
     _round: PendingRound = dataclasses.field(repr=False)
     _slot: int = dataclasses.field(repr=False)
     _result: ScanResult | None = dataclasses.field(default=None, repr=False)
+    _arrival: float | None = dataclasses.field(default=None, repr=False)
+    _stats: SessionStats | None = dataclasses.field(default=None, repr=False)
+    _clock: Callable[[], float] | None = dataclasses.field(default=None, repr=False)
 
     @property
     def num_windows(self) -> int:
@@ -102,6 +112,10 @@ class ServedFeed:
         """The session's trimmed result (materializes on first read)."""
         if self._result is None:
             self._result = self._round.result().sensor(self._slot)
+            if self._arrival is not None:
+                self._stats.record_result_latency(
+                    (self._clock() - self._arrival) * 1e3
+                )
         return self._result
 
 
@@ -591,7 +605,8 @@ class DetectionService:
     def _retire(self, keep: int) -> None:
         """Await the oldest in-flight rounds until at most ``keep`` remain."""
         while len(self._inflight) > keep:
-            self._inflight.pop(0).wait()
+            with TraceAnnotation("service.retire"):
+                self._inflight.pop(0).wait()
 
     def _step(
         self, by_slot: dict[int, int], final_slots: tuple[int, ...]
@@ -620,8 +635,9 @@ class DetectionService:
         """
         chunks: list = [None] * self.capacity
         arrivals: dict[int, float | None] = {}
-        for slot, sid in by_slot.items():
-            chunks[slot], arrivals[sid] = self._sessions[sid].take()
+        with TraceAnnotation("service.take"):
+            for slot, sid in by_slot.items():
+                chunks[slot], arrivals[sid] = self._sessions[sid].take()
         final = np.zeros(self.capacity, bool)
         if final_slots:
             final[list(final_slots)] = True
@@ -673,7 +689,8 @@ class DetectionService:
             served.append(
                 ServedFeed(
                     sid=sid, latency_ms=latency_ms or 0.0,
-                    _round=pending, _slot=slot,
+                    _round=pending, _slot=slot, _arrival=arrival,
+                    _stats=sess.stats, _clock=self.clock,
                 )
             )
         if self.max_inflight_rounds == 1:

@@ -58,6 +58,12 @@ SHED_POLICIES = (SHED_REJECT, SHED_DROP_OLDEST)
 MAX_LATENCY_SAMPLES = 1024
 
 
+def _append_bounded(samples: list[float], value: float) -> None:
+    samples.append(value)
+    if len(samples) > MAX_LATENCY_SAMPLES:
+        del samples[: len(samples) - MAX_LATENCY_SAMPLES]
+
+
 @dataclasses.dataclass(frozen=True)
 class SessionError:
     """One structured fault record on a session (or service) timeline.
@@ -78,9 +84,13 @@ class SessionError:
 class SessionStats:
     """Monotone per-session counters plus service-latency samples.
 
-    ``latency_ms`` keeps only the most recent :data:`MAX_LATENCY_SAMPLES`
-    samples — percentiles describe recent behaviour, and a session
-    feeding at live cadence for days cannot grow host memory unboundedly.
+    ``latency_ms`` (queue wait: oldest queued arrival -> round dispatched)
+    and ``result_latency_ms`` (oldest queued arrival -> the session's
+    result on the host, stamped when the served feed's ``result`` is
+    first read) each keep only the most recent
+    :data:`MAX_LATENCY_SAMPLES` samples — percentiles describe recent
+    behaviour, and a session feeding at live cadence for days cannot
+    grow host memory unboundedly.
 
     Shed accounting is exact by construction: every event offered to
     :meth:`SensorSession.accept` on a live session is either accepted
@@ -106,11 +116,13 @@ class SessionStats:
     steps: int = 0  # fleet steps this session's chunks rode in
     windows: int = 0  # windows closed and returned to the session
     latency_ms: list[float] = dataclasses.field(default_factory=list)
+    result_latency_ms: list[float] = dataclasses.field(default_factory=list)
 
     def record_latency(self, latency_ms: float) -> None:
-        self.latency_ms.append(latency_ms)
-        if len(self.latency_ms) > MAX_LATENCY_SAMPLES:
-            del self.latency_ms[: len(self.latency_ms) - MAX_LATENCY_SAMPLES]
+        _append_bounded(self.latency_ms, latency_ms)
+
+    def record_result_latency(self, latency_ms: float) -> None:
+        _append_bounded(self.result_latency_ms, latency_ms)
 
     def latency_percentile(self, q: float) -> float:
         """q-th percentile of the retained latency samples (0 when none)."""

@@ -134,6 +134,14 @@ def test_fleet_step_compiles_for_v5e(sds, monkeypatch, route):
         False,
     ).compile().as_text()
     assert ("tpu_custom_call" in text) == (route != "default")
+    # Stable names for the profiler: the step's scopes, and the fused
+    # kernel's own name on its custom call.
+    assert "window_core" in text and "carry_reselect" in text
+    if route == "megakernel":
+        kernel = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+        assert kernel and all(
+            "window_pipeline" in ln.split(" = ", 1)[0] for ln in kernel
+        ), kernel
     if config.use_kernels:  # the ragged-wire decoder routes event_unpack
         n = wire_pad(s * w * cap)
         decode = fleet_mod.make_wire_fn.__wrapped__(cap, True)
@@ -142,4 +150,4 @@ def test_fleet_step_compiles_for_v5e(sds, monkeypatch, route):
             sds((n // 32,), jnp.uint32), sds((s, w + 1), jnp.int32),
             sds((5, 256), jnp.int32),
         ).compile().as_text()
-        assert "tpu_custom_call" in text
+        assert "tpu_custom_call" in text and "unpack_wire" in text
