@@ -314,7 +314,11 @@ class WireStats:
     ``d2h_bytes`` count the copy-back: one transfer per stacked output
     leaf a round's first host read brings over, and those leaves' bytes
     (:meth:`FleetResult._host_view`; on the CPU backend the same leaves
-    are host views, counted alike).
+    are host views, counted alike). ``cluster_slots`` counts the cluster
+    slots the step computed (K per window, padded windows included) and
+    ``clusters_valid`` the valid ones among them, read from the same
+    copied-back ``valid`` leaf: their ratio is the share of the
+    megakernel's K-slot work that its valid-prefix loops still run.
     """
 
     rounds: int = 0
@@ -324,6 +328,8 @@ class WireStats:
     spilled: int = 0  # events that took the exact int32 spill lane
     d2h_transfers: int = 0  # output leaves copied back to the host
     d2h_bytes: int = 0  # bytes of those leaves
+    cluster_slots: int = 0  # K per window the step ran
+    clusters_valid: int = 0  # valid slots among them
 
     @property
     def compression(self) -> float:
@@ -342,6 +348,8 @@ class WireStats:
         self.spilled += other.spilled
         self.d2h_transfers += other.d2h_transfers
         self.d2h_bytes += other.d2h_bytes
+        self.cluster_slots += other.cluster_slots
+        self.clusters_valid += other.clusters_valid
 
 
 @functools.lru_cache(maxsize=None)
@@ -478,6 +486,12 @@ class FleetResult:
                 leaves = jax.tree.leaves(self._host)
                 self._stats.d2h_transfers += len(leaves)
                 self._stats.d2h_bytes += sum(a.nbytes for a in leaves)
+                # The hot-row path leaves out only slots that closed no
+                # window, whose padded windows hold no valid cluster.
+                self._stats.cluster_slots += self.clusters.valid.size
+                self._stats.clusters_valid += int(
+                    np.count_nonzero(self._host[0].valid)
+                )
         return self._host
 
     def _copy_back(self) -> tuple[tuple, dict | None]:

@@ -22,7 +22,7 @@ differ between the Pallas program and the staged program.
 The TPU idioms follow ``patch_metrics.py``: event scatters become one-hot
 compares + MXU matmuls, the pairwise (E, E) same-pixel block replaces the
 sensor-sized histogram (exactly the event-space trick
-``core.events.persistent_event_filter`` uses), and top-K is K unrolled
+``core.events.persistent_event_filter`` uses), and top-K is a loop of
 (max, first-index, mask) passes — the same selection contract as
 ``grid_clustering._top_k_cells``. Each cluster's 48x48 count patch is
 one (48, E) x (E, 48) matmul of row and column one-hots, so no flat
@@ -40,6 +40,16 @@ cluster fields (cluster slot k in lane k; row ``CL_FIELDS.index(f)`` =
 field f; row 9 carries the per-window frame normalizer) and one
 (W, K, LANE) int32 block of per-cluster surfaces (row k = cluster k:
 lanes [0, bins) histogram counts, then s1, s2, s_g, s_e2, edges).
+
+Valid-prefix contract: top-K takes cells in descending count order, so a
+window's valid clusters (count >= min_events) are always the first
+``n_valid = min(K, #cells with count >= min_events)`` slots. Both K-slot
+loops (selection and per-cluster surfaces) run ``n_valid`` times, not K:
+slots past the prefix hold the constant invalid column (count 0, cell -1,
+centroids -1.0 in UQ10.8, origin (0, 0), valid 0, norm), and their
+``surf`` rows are zero. The epilogue masks every metric by ``valid``, so
+the unpacked outputs are bit-identical to the staged path, which computes
+(and discards) those slots' surfaces.
 """
 from __future__ import annotations
 
@@ -130,25 +140,34 @@ def _kernel(
     counts = cell_stats[0:1, :]  # padded cells hold count 0
 
     # --- top-K cells + fixed-point cluster fields -------------------------
+    # Valid slots are a prefix of length n_valid (module doc); both K-slot
+    # loops run over it alone. Padded cells hold count 0.
+    n_valid = jnp.minimum(
+        jnp.sum((counts >= min_events).astype(jnp.int32)), k
+    )
     lane1 = jax.lax.broadcasted_iota(jnp.int32, (1, LANE), 1)
     flat_iota = jax.lax.broadcasted_iota(jnp.int32, (1, c_pad), 1)
-    cl = jnp.zeros((CL_ROWS, LANE), jnp.int32)
-    remaining = counts
-    for kk in range(k):
+    neg = -FX.CENTROID_ONE
+    # An invalid slot's origin is its centroid -1 clipped into the sensor: 0.
+    invalid = jnp.stack([
+        jnp.int32(f) for f in (0, -1, -1, neg, neg, neg, 0, 0, 0)
+    ] + [norm_i] + [jnp.int32(0)] * (CL_ROWS - 10)).reshape(CL_ROWS, 1)
+    # norm_i sits in every lane < k: the wrapper reads lane 0 even when
+    # no slot is valid.
+    cl = jnp.where(lane1 < k, invalid, 0)
+
+    def select(kk, carry):
+        remaining, cl = carry
         top = jnp.max(remaining)
         # First maximum (lowest index) — lax.top_k's stable tie order,
         # matching grid_clustering._top_k_cells.
         idx = jnp.min(jnp.where(remaining == top, flat_iota, c_pad))
-        remaining = jnp.where(
-            flat_iota == idx, jnp.iinfo(jnp.int32).min, remaining
-        )
         sel = flat_iota == idx
-        cnt = top
+        remaining = jnp.where(sel, jnp.iinfo(jnp.int32).min, remaining)
         sx = jnp.sum(jnp.where(sel, cell_stats[1:2, :], 0))
         sy = jnp.sum(jnp.where(sel, cell_stats[2:3, :], 0))
         st = jnp.sum(jnp.where(sel, cell_stats[3:4, :], 0))
-        validk = cnt >= min_events
-        den = jnp.maximum(cnt, 1)
+        den = jnp.maximum(top, 1)
 
         def q8(s):
             q = s // den
@@ -157,22 +176,23 @@ def _kernel(
                 r * FX.CENTROID_ONE, den
             )
 
-        neg = jnp.int32(-FX.CENTROID_ONE)
-        ox = jnp.where(validk, FX.round_div_half_even(sx, den), -1)
-        oy = jnp.where(validk, FX.round_div_half_even(sy, den), -1)
+        ox = FX.round_div_half_even(sx, den)
+        oy = FX.round_div_half_even(sy, den)
         col = jnp.stack([
-            jnp.where(validk, cnt, 0),
-            jnp.where(validk, idx % grid_w, -1),
-            jnp.where(validk, idx // grid_w, -1),
-            jnp.where(validk, q8(sx), neg),
-            jnp.where(validk, q8(sy), neg),
-            jnp.where(validk, q8(st), neg),
+            top,
+            idx % grid_w,
+            idx // grid_w,
+            q8(sx),
+            q8(sy),
+            q8(st),
             jnp.clip(ox - window // 2, 0, width - window),
             jnp.clip(oy - window // 2, 0, height - window),
-            validk.astype(jnp.int32),
+            jnp.int32(1),
             norm_i,
         ] + [jnp.int32(0)] * (CL_ROWS - 10)).reshape(CL_ROWS, 1)
-        cl = cl + jnp.where(lane1 == kk, col, 0)
+        return remaining, jnp.where(lane1 == kk, col, cl)
+
+    _, cl = jax.lax.fori_loop(0, n_valid, select, (counts, cl))
 
     # --- per-cluster integer metric surfaces ------------------------------
     cf = c.astype(jnp.float32)
@@ -230,8 +250,9 @@ def _kernel(
         ], axis=1)  # (1, LANE)
         return surf + jnp.where(rowk == kk, row, 0)
 
+    # Rows at or past n_valid stay zero; the epilogue masks them by valid.
     surf = jax.lax.fori_loop(
-        0, k, per_cluster, jnp.zeros((k, LANE), jnp.int32)
+        0, n_valid, per_cluster, jnp.zeros((k, LANE), jnp.int32)
     )
 
     cl_ref[...] = cl

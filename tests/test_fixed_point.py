@@ -264,6 +264,61 @@ def _assert_mega_matches_staged(stacked):
         got = np.asarray(mets_k[name]).view(np.int32)
         want = np.asarray(mets_r[name]).view(np.int32)
         np.testing.assert_array_equal(got, want, err_msg=name)
+    return fc_k
+
+
+def _cells_batch(cells, capacity=256):
+    """One window of ``n`` events in distinct pixels of each 16-px grid
+    cell ``(cx, cy, n)``, in list order (all inside the default ROI)."""
+    xs, ys = [np.zeros(0, int)], [np.zeros(0, int)]
+    for cx, cy, n in cells:
+        i = np.arange(n)
+        xs.append(cx * 16 + i % 16)
+        ys.append(cy * 16 + i // 16)
+    x, y = np.concatenate(xs), np.concatenate(ys)
+    return batch_from_arrays(x, y, np.arange(len(x)), np.zeros(len(x)), capacity)
+
+
+def _prefix_boundary_cells():
+    """name -> (cells, valid clusters): the megakernel runs its K-slot
+    loops only over the valid prefix, so pin the prefix's edges."""
+    k = CONFIG.grid.max_clusters
+    me = CONFIG.grid.min_events
+    grid = [(cx, cy) for cy in range(2, 25, 2) for cx in range(2, 35, 2)]
+    return {
+        # K valid cells (counts me..me+2), the next ones one short.
+        "exactly_k_valid": (
+            [(cx, cy, me + i % 3) for i, (cx, cy) in enumerate(grid[:k])]
+            + [(cx, cy, me - 1) for cx, cy in grid[k:k + 16]],
+            k,
+        ),
+        # More valid cells than slots, all tied: min(K, ...) clips, and
+        # the slots take the lowest cell indices.
+        "over_k_valid": ([(cx, cy, me + 1) for cx, cy in grid[:k + 8]], k),
+        # A cell at exactly min_events beside one at min_events - 1.
+        "threshold_neighbours": (
+            [(10, 10, me), (11, 10, me - 1), (20, 12, me + 4), (21, 12, 2)],
+            2,
+        ),
+        # Valid cells tied in count, listed from the highest cell index
+        # down: slots follow first-index order, not arrival order.
+        "tied_valid_counts": (
+            [(cx, cy, me + 2) for cx, cy in reversed(grid[:6])]
+            + [(cx, cy, me + 4) for cx, cy in reversed(grid[40:43])]
+            + [(30, 20, 1)],
+            9,
+        ),
+        "all_padding": ([], 0),
+    }
+
+
+@pytest.mark.parametrize("name", sorted(_prefix_boundary_cells()))
+def test_megakernel_bit_identical_at_valid_prefix_edges(name):
+    cells, n_valid = _prefix_boundary_cells()[name]
+    fc = _assert_mega_matches_staged(_stack([_cells_batch(cells)]))
+    valid = np.asarray(fc.valid)[0]
+    assert valid.sum() == n_valid
+    assert valid[:n_valid].all()  # the valid slots are a prefix
 
 
 def test_megakernel_bit_identical_random_windows():

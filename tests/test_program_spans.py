@@ -189,10 +189,51 @@ def test_copy_back_counters_match_the_leaves(hot):
     assert fp.wire_stats.d2h_bytes == want
 
 
+def _clustered_stream(seed: int, n: int, t0_us: int = 0, dt_us: int = 100):
+    """Events around four spots, so cells reach ``min_events`` and the
+    windows hold some valid clusters among many empty slots."""
+    rng = np.random.default_rng(seed)
+    spots = rng.integers(60, 380, (4, 2))
+    pick = rng.integers(0, 4, n)
+    return (
+        (spots[pick, 0] + rng.integers(-6, 7, n)).astype(np.int64),
+        (spots[pick, 1] + rng.integers(-6, 7, n)).astype(np.int64),
+        t0_us + (np.arange(n, dtype=np.int64) + 1) * dt_us,
+        rng.integers(0, 2, n).astype(np.int64),
+    )
+
+
+@pytest.mark.parametrize("hot", [4, 1], ids=["full", "hot_rows"])
+def test_cluster_slot_counters_match_the_valid_leaf(hot):
+    fp = FleetPipeline(PipelineConfig(), n_sensors=4)
+    slots = valid = 0
+    for rnd in range(2):
+        chunks = [None] * 4
+        for s in range(hot):
+            chunks[s] = _clustered_stream(
+                10 * rnd + s, 700 + 200 * s, t0_us=200_000 * rnd
+            )
+        res = fp.feed(chunks)
+        res.sensor(0)
+        res.sensor(hot - 1)  # a second read counts nothing more
+        leaf = np.asarray(res.clusters.valid)  # (S, W_max, K)
+        # Padded windows are counted: the step ran K slots for each.
+        assert leaf.shape[:2] == (4, res.n_windows.max())
+        assert leaf.size > res.total_windows * leaf.shape[-1]
+        slots += leaf.size
+        valid += int(np.count_nonzero(leaf))
+        assert fp.wire_stats.cluster_slots == slots
+        assert fp.wire_stats.clusters_valid == valid
+    assert 0 < valid < slots
+
+
 def test_wire_stats_add_sums_the_copy_back():
-    a = WireStats(rounds=1, d2h_transfers=22, d2h_bytes=1000)
-    a.add(WireStats(rounds=2, d2h_transfers=3, d2h_bytes=24))
+    a = WireStats(rounds=1, d2h_transfers=22, d2h_bytes=1000,
+                  cluster_slots=320, clusters_valid=7)
+    a.add(WireStats(rounds=2, d2h_transfers=3, d2h_bytes=24,
+                    cluster_slots=64, clusters_valid=2))
     assert (a.rounds, a.d2h_transfers, a.d2h_bytes) == (3, 25, 1024)
+    assert (a.cluster_slots, a.clusters_valid) == (384, 9)
 
 
 # --- latency at the result ------------------------------------------------------
