@@ -5,7 +5,9 @@ the readers see must leave what the accepted metrics read unchanged: the
 loop's own spans set the traced window, and each reader reads the same
 spans and device events as before. The values are what the accepted
 readers return on the fixtures (TPU v5 lite, a few rounds of the fixed
-route, tracking on).
+route: tracking on in ``paper_vga_fixed.*``, recorded before the program
+had spans of its own; off in ``paper_vga_fixed_untracked.backlog``, with
+the program's ``service.*`` and ``fleet.*`` spans).
 """
 from __future__ import annotations
 
@@ -29,6 +31,16 @@ PINNED = {
     ("paper_vga_fixed.backlog", "result_ms.backlog"): 14.846602749999994,
     ("paper_vga_fixed.backlog", "window_pipeline_roofline.backlog"): 0.5579547309669073,
     ("paper_vga_fixed.backlog", "wire_decode_device_ms.backlog"): 5.861055499999996,
+    ("paper_vga_fixed_untracked.backlog", "copy_back_ms.backlog"): 10.475874666666671,
+    ("paper_vga_fixed_untracked.backlog", "copy_back_wait_ms.backlog"): 0.02095333333333264,
+    ("paper_vga_fixed_untracked.backlog", "device_idle_share.backlog"): 59.95492834519207,
+    ("paper_vga_fixed_untracked.backlog", "dispatch_wait_ms.backlog"): 0.08345333333332923,
+    ("paper_vga_fixed_untracked.backlog", "fleet_step_device_ms.backlog"): 6.4216644999999986,
+    ("paper_vga_fixed_untracked.backlog", "pump_host_ms.backlog"): 17.730993333333327,
+    ("paper_vga_fixed_untracked.backlog", "pump_ms.backlog"): 18.485403333333334,
+    ("paper_vga_fixed_untracked.backlog", "result_ms.backlog"): 10.925982999999995,
+    ("paper_vga_fixed_untracked.backlog", "window_pipeline_roofline.backlog"): 4.01103904537979,
+    ("paper_vga_fixed_untracked.backlog", "wire_decode_device_ms.backlog"): 5.83168783333333,
 }
 
 

@@ -16,7 +16,7 @@ from pathlib import Path
 import numpy as np
 import pytest
 
-from tpubench import bench, check, context, reference, traffic
+from tpubench import bench, check, context, host, reference, traffic
 from tpubench import trace as T
 
 HERE = Path(__file__).resolve().parent
@@ -161,10 +161,12 @@ def test_window_pipeline_cost_matches_the_program_model():
 
 # --- the trace reduction, on a recorded chip trace ---------------------------
 
-@pytest.mark.parametrize("fixture", ["paper_vga_fixed.live", "paper_vga_fixed.backlog"])
+@pytest.mark.parametrize("fixture", [
+    "paper_vga_fixed.live", "paper_vga_fixed.backlog", "paper_vga_fixed_untracked.backlog"])
 def test_trace_reduction_on_a_recorded_chip_trace(fixture):
     """Every metric reader of the fixture's traffic on a trace recorded on a
-    TPU v5 lite (a few rounds of the fixed route, tracking on)."""
+    TPU v5 lite (a few rounds of the fixed route). The fixtures recorded
+    before the program had spans of its own leave their readers silent."""
     digest = T.Digest.load(FIXTURES / f"{fixture}.json.gz")
     config, traffic_name = fixture.split(".")
     plan = bench.plan_for(config, traffic_name)
@@ -174,6 +176,9 @@ def test_trace_reduction_on_a_recorded_chip_trace(fixture):
     names = sorted(p.name[:-3] for p in (HERE / "metrics").glob(f"*.{traffic_name}.py"))
     assert names
     values = {n: context.reader(n)(ctx) for n in names}
+    if not ctx.has_spans(*T.PROGRAM_SPANS):
+        silent = {n for n in names if n in PROGRAM_SPAN_READERS}
+        assert all(values.pop(n) is None for n in silent), values
     assert all(v is not None for v in values.values()), values
     for name, v in values.items():
         if name.endswith("_roofline") or "idle_share" in name:
@@ -184,7 +189,13 @@ def test_trace_reduction_on_a_recorded_chip_trace(fixture):
     assert 0 < len(out["device_ops"]) <= 10 and 0 < len(out["idle_gaps"]) <= 10
     assert any(k.startswith("jit_step/") for k, _ in out["device_ops"])
     labels = {k for k, _ in out["idle_gaps"]}
-    assert labels <= set(T.SPANS) | {"none"}
+    assert labels <= set(T.SPANS) | set(T.PROGRAM_SPANS) | {"none"}
+
+
+PROGRAM_SPAN_READERS = {
+    "pump_host_ms.backlog", "dispatch_wait_ms.backlog", "copy_back_ms.backlog",
+    "copy_back_wait_ms.backlog",
+}
 
 
 def test_interval_arithmetic():
@@ -195,6 +206,56 @@ def test_interval_arithmetic():
     assert T.complement(a, 0, 7).tolist() == [[3, 5], [6, 7]]
     assert T.length(a) == 4
     assert T.op_shape("%vmap.1 = (s32[16,50,16,128]{3,2}, s32[1]) custom-call()") == (16, 50, 16, 128)
+
+
+# --- the host line ------------------------------------------------------------
+
+def test_host_line_of_a_cpu_run_parses():
+    """A run prints one ``# host:`` line: the loop's steps per round, the
+    window's events by 5 s slice, which sum to the window's events, and
+    what the loop's thread and the process used."""
+    lines = []
+    _cell_run(log=lambda *a: lines.append(" ".join(map(str, a))))
+    host_lines = [ln for ln in lines if ln.startswith("# host: ")]
+    assert len(host_lines) == 1
+    h = json.loads(host_lines[0][len("# host: "):])
+    events = int(next(ln for ln in lines if ln.startswith("# window ")).rsplit(" ", 1)[1])
+    assert sum(h["slice_events"]) == events > 0
+    assert len(h["slice_events"]) == len(h["slice_events_per_s"]) == 1
+    assert set(h["steps_ms"]) == {"generator", "feed", "pump", "result"}
+    assert all(0 <= p50 <= p90 for p50, p90 in h["steps_ms"].values())
+    t = h["thread"]
+    assert set(t) == {"utime", "stime", "cpu_share"}
+    assert t["utime"] >= 0 and t["stime"] >= 0 and 0 < t["cpu_share"]
+    assert h["process_cpu_s"] >= t["utime"] + t["stime"] - 1e-3
+    assert h["gc2"]["count"] >= 0 and h["gc2"]["ms"] >= 0
+
+
+@dataclasses.dataclass
+class _Round:
+    host_time: float
+    events: int
+
+
+@pytest.mark.parametrize("times,t_end,events,secs", [
+    ([0.5, 4.9, 5.0, 12.0], 12.0, [2, 1, 1], [5, 5, 2]),  # the last slice 2 s long
+    ([1.0, 14.9, 15.2], 15.0, [1, 0, 2], [5, 5, 5.2]),  # the drain's round in the last
+    ([0.1], 1.0, [1], [1.0]),  # a window shorter than a slice
+])
+def test_window_slices_sum_to_the_window(times, t_end, events, secs):
+    s = host.slices([_Round(t, 10) for t in times], 0.0, t_end)
+    assert s["slice_events"] == [10 * n for n in events]
+    assert np.allclose(s["slice_events_per_s"], 10 * np.asarray(events) / np.asarray(secs))
+
+
+def test_watch_counts_generation_2_collections():
+    import gc
+
+    hooks = len(gc.callbacks)
+    with host.Watch() as w:
+        gc.collect(2)
+        gc.collect(0)
+    assert w.fields()["gc2"]["count"] == 1 and len(gc.callbacks) == hooks
 
 
 # --- the command -----------------------------------------------------------
@@ -293,7 +354,7 @@ def _cpu_run(config: str, traffic_name: str, traced: bool = False, seconds: floa
                           require_tpu=False, log=lambda *a: None)
 
 
-def _cell_run(metrics_impl_cpu: str = "staged"):
+def _cell_run(metrics_impl_cpu: str = "staged", log=lambda *a: None):
     """The benchmark's cell on this CPU: 16 stations (the cell's slot tier),
     0.1 s chunks, and the fixed datapath staged in jnp, whose integers the
     Pallas kernel reproduces bit for bit, in place of the interpreted
@@ -306,7 +367,7 @@ def _cell_run(metrics_impl_cpu: str = "staged"):
         small("backlog", stations=16, tile_s=0.4), chunk_ms=100.0
     )
     return bench.run_cell(plan, SEED, 0.3, False, time.perf_counter(),
-                          require_tpu=False, log=lambda *a: None)
+                          require_tpu=False, log=log)
 
 
 @pytest.mark.parametrize("traced", [False, True])

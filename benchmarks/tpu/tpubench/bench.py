@@ -133,7 +133,7 @@ def run_cell(plan: dict, seed: int, seconds: float, traced: bool, t_start: float
     """Run one cell once; returns the result object (the last line).
     ``probe``, when given, receives the check's input and per-field detail."""
     from tpubench import check as C
-    from tpubench import context, drive
+    from tpubench import context, drive, host
     from tpubench import trace as T
 
     cell, cfg, traffic = plan["cell"], plan["cfg"], plan["traffic"]
@@ -150,7 +150,8 @@ def run_cell(plan: dict, seed: int, seconds: float, traced: bool, t_start: float
     window = min(seconds, traffic.trace_s) if traced else seconds
     if traced:
         T.start(TRACE_DIR)
-    rec = drv.window(window)
+    with host.Watch() as watch:
+        rec = drv.window(window)
     digest = T.stop(TRACE_DIR) if traced else None
     compiles1 = sysm.compile_counts()
     stats = dev.memory_stats() or {}
@@ -166,7 +167,12 @@ def run_cell(plan: dict, seed: int, seconds: float, traced: bool, t_start: float
     log(f"# compiles inside the window: step {compiles1['step'] - compiles0['step']}, "
         f"decode {compiles1['decode'] - compiles0['decode']}")
     log(f"# wire: rounds {wire.rounds} wire_bytes {wire.wire_bytes} dense_bytes "
-        f"{wire.dense_bytes} compression {wire.compression:.4f} spilled {wire.spilled}")
+        f"{wire.dense_bytes} compression {wire.compression:.4f} spilled {wire.spilled} "
+        f"d2h_transfers {wire.d2h_transfers} d2h_bytes {wire.d2h_bytes} cluster_slots "
+        f"{wire.cluster_slots} clusters_valid {wire.clusters_valid}")
+    log("# host: " + json.dumps(dict(
+        steps_ms=host.steps_ms(rec.steps), **host.slices(rounds, rec.t0, rec.t_end),
+        **watch.fields())))
     shapes = sorted({r.shape for r in rounds})
     log(f"# step shapes (slots, windows): {shapes}")
     metrics = {}
@@ -252,11 +258,13 @@ def _breakdown(ctx) -> dict:
         key = f"{mod}/{op}"
         per_op[key] = per_op.get(key, 0.0) + (e - s)
     top_ops = sorted(per_op.items(), key=lambda kv: -kv[1])[:10]
-    # Idle time goes to the innermost host span open over it: the loop's
-    # own steps first, the waits that hold them last.
+    # Idle time goes to the innermost host span open over it: the
+    # program's spans, then the loop's own steps, the waits that hold them
+    # last.
     remaining = T.complement(ctx.busy, ctx.lo, ctx.hi)
     idle: dict[str, float] = {}
-    for name in ("result", "pump", "feed", "generator", "drain", "wait_beat"):
+    loop = ("result", "pump", "feed", "generator", "drain", "wait_beat")
+    for name in T.PROGRAM_SPANS + loop:
         spans = T.merge([sp[1:3] for sp in d.spans if sp[0] == name])
         covered = T.intersect(remaining, spans)
         if T.length(covered) > 0:

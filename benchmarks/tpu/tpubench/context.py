@@ -77,8 +77,17 @@ class Context:
     def per_round_ms(self, seconds: float) -> float | None:
         return seconds * 1e3 / self.n_rounds if self.n_rounds else None
 
-    def span_s(self, name: str) -> float:
-        return T.length(T.clip(T.merge([s[1:3] for s in self.spans(name)]), self.lo, self.hi))
+    def span_s(self, *names: str, outside: str | None = None) -> float:
+        """Seconds of the window under any span of ``names``, less what lies
+        under the spans named ``outside``."""
+        rows = T.clip(T.merge([s[1:3] for n in names for s in self.spans(n)]), self.lo, self.hi)
+        if outside is not None:
+            rows = T.intersect(rows, T.complement(
+                T.merge([s[1:3] for s in self.spans(outside)]), self.lo, self.hi))
+        return T.length(rows)
+
+    def has_spans(self, *names: str) -> bool:
+        return any(s[0] in names for s in self.digest.spans)
 
     def round_intervals(self) -> np.ndarray:
         """Union over rounds of [start of its pump, end of its result]."""

@@ -45,6 +45,9 @@ class Record:
     t_done: float = 0.0  # last result of the window on the host
     first_chunk: int = 0  # chunk index of the window's first beat
     next_chunk: int = 0  # first chunk not fed
+    # Host seconds of each loop step, per round, in the window and its drain.
+    steps: dict = dataclasses.field(
+        default_factory=lambda: {k: [] for k in ("generator", "feed", "pump", "result")})
 
 
 class Driver:
@@ -64,13 +67,22 @@ class Driver:
 
     # --- one round ------------------------------------------------------
     def _round(self, b: int) -> None:
+        t0 = clock()
         with TraceAnnotation("generator"):
             chunks = [s.chunk(b) for s in self.streams]
+        t1 = clock()
         with TraceAnnotation("feed"):
             for sid, ch in zip(self.sids, chunks):
                 self.svc.feed(sid, *ch)
+        t2 = clock()
         with TraceAnnotation("pump", round=b):
             feeds = self.svc.pump(force=True)
+        t3 = clock()
+        if self.timed:
+            steps = self.rec.steps
+            steps["generator"].append(t1 - t0)
+            steps["feed"].append(t2 - t1)
+            steps["pump"].append(t3 - t2)
         if feeds:
             rnd = Round(b, feeds, self.svc.last_round)
             nw = rnd.handle.n_windows
@@ -88,9 +100,12 @@ class Driver:
         rnd = self.pending[0]
         if not block and not rnd.handle.ready():
             return False
+        t0 = clock()
         with TraceAnnotation("result", round=rnd.index):
             results = [fd.result for fd in rnd.feeds]
             rnd.host_time = clock()
+        if self.timed:
+            self.rec.steps["result"].append(rnd.host_time - t0)
         self.pending.pop(0)
         events = 0
         for fd, res in zip(rnd.feeds, results):

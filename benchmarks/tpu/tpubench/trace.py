@@ -1,7 +1,8 @@
 """Profiler capture and its reduction to a digest the metric readers use.
 
-The host spans are the benchmark's own ``TraceAnnotation``s (``SPANS``), on
-the profiler's clock like the device events. A digest holds, within the
+The host spans are the benchmark's own ``TraceAnnotation``s (``SPANS``), which
+set the traced window, and the program's own inside them (``PROGRAM_SPANS``),
+on the profiler's clock like the device events. A digest holds, within the
 traced window: the host spans, the device's programs (``XLA Modules``) and
 its operations (``XLA Ops``), each as (name, start, end) in seconds from the
 start of the capture. The same digest, saved as JSON, is the fixture the
@@ -19,6 +20,11 @@ from pathlib import Path
 import numpy as np
 
 SPANS = ("generator", "feed", "pump", "result", "wait_beat", "drain")
+# The service's and the fleet driver's spans, one set per round.
+PROGRAM_SPANS = (
+    "service.take", "service.retire", "fleet.window", "fleet.staging_wait", "fleet.pack",
+    "fleet.stage", "fleet.dispatch", "fleet.copy_wait", "fleet.copy_back",
+)
 _SHAPE = re.compile(r"^\S+ = \(?[a-z0-9]+\[([0-9,]*)\]")
 
 
@@ -87,7 +93,7 @@ def _reduce(data) -> Digest:
         if plane.name.startswith("/host:"):
             for line in plane.lines:
                 for ev in line.events:
-                    if ev.name in SPANS:
+                    if ev.name in SPANS or ev.name in PROGRAM_SPANS:
                         rnd = -1
                         for key, val in ev.stats:
                             if key == "round":
@@ -109,7 +115,8 @@ def _reduce(data) -> Digest:
                         t0 = ev.start_ns * 1e-9
                         ops.append((ix, t0, t0 + ev.duration_ns * 1e-9))
     spans.sort(key=lambda s: s[1])
-    lo, hi = (spans[0][1], max(s[2] for s in spans)) if spans else (0.0, 0.0)
+    loop = [s for s in spans if s[0] in SPANS]
+    lo, hi = (loop[0][1], max(s[2] for s in loop)) if loop else (0.0, 0.0)
     spans = [s for s in spans if s[2] > lo and s[1] < hi]
     modules = [m for m in modules if m[2] > lo and m[1] < hi]
     arr = np.asarray(ops, np.float64).reshape(-1, 3)
